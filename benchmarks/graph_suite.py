@@ -29,6 +29,7 @@ import time
 import numpy as np
 
 from repro.analysis import replay_graph
+from repro.compile_cache import enable_compile_cache
 from repro.configs import ARCHS
 from repro.core import (MappingProblem, PlanCache, Stencil, arch_comm_graph,
                         graph_create, parse_plan)
@@ -189,6 +190,7 @@ def main():
                          "(CI smoke)")
     ap.add_argument("--json", default=None, help="dump rows + claims")
     args = ap.parse_args()
+    enable_compile_cache()
     out = {"parity": run_parity(args.tiny),
            "arch_dci": run_arch_dci(args.tiny)}
     print_graph_table(out)

@@ -1,5 +1,6 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"
 # ^ before any jax import (same contract as launch/dryrun.py)
 """§Perf hillclimbing driver: run a cell's baseline + named variants, print
 the three roofline terms and memory for each, and save the iteration log.

@@ -35,6 +35,7 @@ import time
 
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import (CartGrid, MapperInapplicable, Stencil, evaluate,
                         get_mapper)
 from repro.core.mapping import MAPPERS
@@ -794,6 +795,7 @@ def main():
                          "blocked; --json emits the BENCH_8.json payload)")
     ap.add_argument("--json", default=None, help="also dump rows as JSON")
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.hier:
         big = run_hier_big()

@@ -19,6 +19,8 @@ def main() -> None:
     ap.add_argument("--skip", default="",
                     help="comma list: fig8,fig67,fig9,roofline,kernels")
     args = ap.parse_args()
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     skip = set(args.skip.split(",")) if args.skip else set()
 
     from . import (exchange_time, instantiation_time, kernels_bench,

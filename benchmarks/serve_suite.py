@@ -30,6 +30,7 @@ import time
 
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import CartGrid, Stencil, evaluate, get_mapper
 from repro.core.plan import (MappingProblem, PlanCache, cart_create,
                              parse_plan)
@@ -238,6 +239,7 @@ def main():
                     help="first instance only (smoke)")
     ap.add_argument("--json", default=None, help="dump rows + claims")
     args = ap.parse_args()
+    enable_compile_cache()
     out = run_serve(QUICK_INSTANCES if args.quick else INSTANCES)
     print_serve_table(out)
     print()

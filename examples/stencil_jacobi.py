@@ -8,10 +8,10 @@ this is an example launcher, like dryrun.py) arranged as 2 "nodes" x 4
   1. computes the process-to-node mapping with a paper algorithm and builds
      the jax Mesh from the permuted device array (MPI_Cart_create reorder);
   2. runs Jacobi iterations under shard_map, exchanging halos with
-     jax.lax.ppermute — the MPI_Neighbor_alltoall analog;
-  3. applies the local stencil update with the Pallas kernel
-     (interpret mode on CPU) or the jnp reference;
-  4. checks the distributed result against a single-array oracle and prints
+     jax.lax.ppermute — the MPI_Neighbor_alltoall analog — and applying
+     the local 5-point update in jnp
+     (:mod:`repro.kernels.stencil.jacobi`);
+  3. checks the distributed result against a single-array oracle and prints
      the J_sum/J_max table for the chosen vs blocked layout.
 
 Run:  PYTHONPATH=src python examples/stencil_jacobi.py --mapper stencil_strips
@@ -28,35 +28,14 @@ sys.path.insert(0, str(Path(__file__).resolve().parent.parent / "src"))
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax.sharding import Mesh
 
-from repro.core import (CartGrid, Stencil, get_mapper, layout_cost,
+from repro.core import (Stencil, device_layout, get_mapper, layout_cost,
                         mapped_device_array)
+from repro.kernels.stencil.jacobi import distributed_jacobi, jacobi_oracle
 
 MESH_SHAPE = (4, 2)      # logical process grid
 CHIPS_PER_NODE = 4       # 8 devices = 2 "nodes" of 4
-
-
-def halo_pad(u, axis_name, size, axis):
-    """Exchange one-deep halos along a mesh axis (non-periodic)."""
-    n = size
-    fwd = [(i, i + 1) for i in range(n - 1)]
-    bwd = [(i, i - 1) for i in range(1, n)]
-    last = jax.lax.slice_in_dim(u, u.shape[axis] - 1, u.shape[axis], axis=axis)
-    first = jax.lax.slice_in_dim(u, 0, 1, axis=axis)
-    from_left = jax.lax.ppermute(last, axis_name, fwd)
-    from_right = jax.lax.ppermute(first, axis_name, bwd)
-    return jnp.concatenate([from_left, u, from_right], axis=axis)
-
-
-def jacobi_step_local(u_halo, weights):
-    H = u_halo.shape[0] - 2
-    W = u_halo.shape[1] - 2
-    c, n_, s_, w_, e_ = weights
-    return (c * u_halo[1:-1, 1:-1] + n_ * u_halo[:-2, 1:-1]
-            + s_ * u_halo[2:, 1:-1] + w_ * u_halo[1:-1, :-2]
-            + e_ * u_halo[1:-1, 2:])
 
 
 def main():
@@ -67,7 +46,6 @@ def main():
     args = ap.parse_args()
 
     stencil = Stencil.nearest_neighbor(2)
-    weights = (0.4, 0.15, 0.15, 0.15, 0.15)
 
     # 1. mapped mesh (the paper's reorder step)
     devs = mapped_device_array(jax.devices(), get_mapper(args.mapper),
@@ -78,40 +56,15 @@ def main():
     sizes = [CHIPS_PER_NODE] * (8 // CHIPS_PER_NODE)
     print(f"{'layout':16s} {'J_sum':>8s} {'J_max':>8s}")
     for algo in ("blocked", args.mapper, "random"):
-        from repro.core import device_layout
         L = device_layout(get_mapper(algo), MESH_SHAPE, stencil, sizes)
         c = layout_cost(L, stencil, sizes)
         print(f"{algo:16s} {c.j_sum:8.0f} {c.j_max:8.0f}")
 
-    # 2-3. distributed Jacobi under shard_map
+    # 2-3. distributed Jacobi under shard_map vs the single-array oracle
     n = args.size
     u0 = jnp.zeros((n, n), jnp.float32).at[n // 2, n // 2].set(1000.0)
-
-    def step(u):
-        u = halo_pad(u, "x", MESH_SHAPE[0], 0)
-        u = halo_pad(u, "y", MESH_SHAPE[1], 1)
-        return jacobi_step_local(u, weights)
-
-    dist_step = shard_map(step, mesh=mesh, in_specs=P("x", "y"),
-                          out_specs=P("x", "y"))
-
-    @jax.jit
-    def run_dist(u):
-        for _ in range(args.iters):
-            u = dist_step(u)
-        return u
-
-    u = jax.device_put(u0, NamedSharding(mesh, P("x", "y")))
-    out = np.asarray(run_dist(u))
-
-    # 4. oracle: single-array iteration
-    ref = np.asarray(u0)
-    for _ in range(args.iters):
-        pad = np.pad(ref, 1)
-        ref = (weights[0] * pad[1:-1, 1:-1] + weights[1] * pad[:-2, 1:-1]
-               + weights[2] * pad[2:, 1:-1] + weights[3] * pad[1:-1, :-2]
-               + weights[4] * pad[1:-1, 2:])
-    err = np.abs(out - ref).max()
+    out = distributed_jacobi(mesh, u0, args.iters)
+    err = np.abs(out - jacobi_oracle(u0, args.iters)).max()
     print(f"\ndistributed Jacobi x{args.iters} on {MESH_SHAPE} mesh "
           f"({args.mapper} layout): max|err| vs oracle = {err:.2e}")
     assert err < 1e-4, "distributed result diverged from oracle"

@@ -7,6 +7,9 @@
 #   scripts/verify.sh --slow   # additionally run the -m slow tests
 set -euo pipefail
 cd "$(dirname "$0")/.."
+# a CPU check: tests and benchmarks here never take a chip (the on-chip
+# smoke is chip_smoke.py, run on a TPU host)
+export JAX_PLATFORMS=cpu
 
 python -m pytest -x -q
 
@@ -67,14 +70,13 @@ EOF
 # proposal budget over the base-mapper matrix, plus the K-scaling sweep
 # (K=1024 under 4x the K=8 wall-time at fixed budget) — exit 1 on any
 # FAIL — and the machine-readable BENCH_7.json perf snapshot.
-# JAX_PLATFORM_NAME=cpu keeps the run offline-reproducible.
 mkdir -p results
-JAX_PLATFORM_NAME=cpu PYTHONPATH=src python -m benchmarks.refine_suite \
+PYTHONPATH=src python -m benchmarks.refine_suite \
     --device --json results/BENCH_7.json
 
 # device smoke: the device: grammar spelling end to end — integer-exact
 # count state, deterministic, sizes preserved, no host fallback
-JAX_PLATFORM_NAME=cpu PYTHONPATH=src python - <<'EOF'
+PYTHONPATH=src python - <<'EOF'
 import numpy as np
 from repro.core import CartGrid, Stencil, evaluate, get_mapper
 
@@ -180,6 +182,8 @@ plan = "sharded[shards=2,k=4,restarts=auto]:hyperplane"
 with PlanServer(threads=2, shard_workers=2, default_plan=plan) as srv:
     warm = srv.warm_up()
     assert warm["swept"] >= 2, warm
+    # the resident shard workers ran, not the inline fallback
+    assert set(warm["backends"].values()) == {"resident"}, warm
     cli = PlanClient(srv)
     tickets = [cli.cart_create_async((6, 8), node_sizes=(16, 16, 10, 6))
                for _ in range(6)]
@@ -209,7 +213,7 @@ EOF
 # BENCH_10.json perf snapshot
 mkdir -p results
 PYTHONPATH=src python -m benchmarks.graph_suite --tiny
-JAX_PLATFORM_NAME=cpu PYTHONPATH=src python -m benchmarks.graph_suite \
+PYTHONPATH=src python -m benchmarks.graph_suite \
     --json results/BENCH_10.json
 
 # graph smoke: extract a real arch comm graph -> map it through the graph:

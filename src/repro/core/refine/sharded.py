@@ -245,6 +245,19 @@ def _jax_stacked_counts(table: NeighborTable, A: np.ndarray,
                                  .transpose(0, 2, 1)))
 
 
+def worker_context():
+    """The ``multiprocessing`` context of the numpy-only worker pools:
+    fork (spawn where there is none).  Fork keeps the workers cheap: no
+    re-import, and a script fed on stdin can start them.  The children
+    never touch jax, so a parent that already drives a TPU forks safely
+    (checked on a v5e: the pool answers and the warm-up completes; JAX
+    only warns).  A fork server re-imports the package in every worker,
+    which costs more than the per-temperature work it spreads."""
+    methods = multiprocessing.get_all_start_methods()
+    return multiprocessing.get_context(
+        "fork" if "fork" in methods else "spawn")
+
+
 # ---------------------------------------------------------------------------
 # the per-(block, temperature) worker task
 
@@ -332,7 +345,7 @@ class ShardedPortfolioRefiner:
         stacked numpy loop, ``"auto"`` jax exactly when it is importable
         (an environment property; never depends on import order — results
         are bit-identical either way).  Serial backend only: mp workers
-        are numpy-only by design (no jax in forked children), so the flag
+        are numpy-only by design (no jax in worker processes), so the flag
         is inert there.
       Remaining arguments are :class:`PortfolioRefiner`'s, same defaults —
       a bare ``sharded:<base>`` equals a bare ``portfolio:<base>``.
@@ -595,17 +608,13 @@ class ShardedPortfolioRefiner:
 
         executor = None
         if backend == "mp" and S > 1:
-            # fork keeps the workers cheap (no re-import; the tasks are
-            # numpy-only, so jax's forked threadpools are never touched);
-            # spawn is the non-POSIX fallback.  The executor — unlike
-            # multiprocessing.Pool — *raises* BrokenProcessPool when a
-            # worker dies at startup (e.g. spawn under a non-importable
-            # __main__, REPL/stdin scripts), so a broken pool degrades to
-            # the inline path instead of hanging a map() forever.
+            # the executor — unlike multiprocessing.Pool — *raises*
+            # BrokenProcessPool when a worker dies at startup (e.g. spawn
+            # under a non-importable __main__, REPL/stdin scripts), so a
+            # broken pool degrades to the inline path instead of hanging a
+            # map() forever.
             from concurrent.futures import ProcessPoolExecutor
-            methods = multiprocessing.get_all_start_methods()
-            ctx = multiprocessing.get_context(
-                "fork" if "fork" in methods else "spawn")
+            ctx = worker_context()
             n_proc = min(S, os.cpu_count() or 1)
             if self.workers is not None:
                 n_proc = max(1, min(n_proc, self.workers))

@@ -64,6 +64,12 @@ def canon_options(options: Dict[str, object]) -> str:
 #: sound cache key).
 _PLAIN_TYPES = (int, float, bool, str, type(None))
 
+#: refiner stats a refine stage passes through: the engine that ran
+#: (``device[tpu]``, ``host-fallback``, ``resident``, ...), the reason it
+#: delegated, and the device engine's time split
+_ENGINE_STATS = ("backend", "delegated", "t_rounds_s", "t_ladders_s",
+                 "t_polish_s")
+
 
 def _is_plain(v) -> bool:
     if isinstance(v, _PLAIN_TYPES):
@@ -273,4 +279,9 @@ class RefineStage(Stage):
             "initial": (res.initial.j_max, res.initial.j_sum),
             "final": (res.final.j_max, res.final.j_sum),
         }
+        # where the refiner ran, and why it delegated if it did
+        engine_stats = res.stats or {}
+        for key in _ENGINE_STATS:
+            if key in engine_stats:
+                stats[key] = engine_stats[key]
         return StageResult(assignment=res.assignment, stats=stats, result=res)

@@ -664,6 +664,7 @@ class RepairStage(Stage):
         stats = {
             "stage": self.spec(), "kind": "repair", "used_fallback": False,
             "strategy": "grow-fresh", "grow_base": self.grow_base,
+            "backend": "host",
             "orphans": rs.orphans,
             "rehomed_adjacent": rs.rehomed_adjacent,
             "moved": migrated,
@@ -754,6 +755,7 @@ class RepairStage(Stage):
             final_key = (ic.j_max, ic.j_sum)
         stats = {
             "stage": self.spec(), "kind": "repair", "used_fallback": False,
+            "backend": "host",
             "orphans": rs.orphans,
             "rehomed_adjacent": rs.rehomed_adjacent,
             "moved": int(rs.moved.sum()),

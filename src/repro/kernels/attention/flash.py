@@ -19,10 +19,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# renamed TPUCompilerParams -> CompilerParams in newer JAX; accept both
-_CompilerParams = getattr(pltpu, "CompilerParams", None) \
-    or getattr(pltpu, "TPUCompilerParams")
-
 __all__ = ["flash_kernel", "flash_attention_pallas"]
 
 NEG_INF = -1e30
@@ -104,7 +100,7 @@ def flash_attention_pallas(q, k, v, *, causal=True, window=None, scale=None,
             pltpu.VMEM((qb,), jnp.float32),
             pltpu.VMEM((qb,), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q, k, v)

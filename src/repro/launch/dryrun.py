@@ -1,7 +1,9 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"
 # ^ MUST run before any jax import: the dry-run (and only the dry-run) needs
-# 512 placeholder host devices for the production meshes.
+# 512 placeholder host devices for the production meshes, and runs on the
+# CPU even on a TPU host, so it never takes the chip.
 """Multi-pod dry-run: lower + compile every (architecture × input shape) on
 the 16x16 single-pod mesh and the 2x16x16 multi-pod mesh; record memory,
 cost, collective and roofline analysis (EXPERIMENTS.md §Dry-run/§Roofline).

@@ -337,19 +337,25 @@ class PlanServer:
         return self.cache.invalidate(h)
 
     def warm_up(self, names: Optional[Sequence[str]] = None,
-                plan: Union[None, str, MappingPlan] = None) -> Dict[str, int]:
+                plan: Union[None, str, MappingPlan] = None) -> dict:
         """Sweep the topology registry (or ``names``) through the solve
         path so production requests hit a warm cache.  Runs in the calling
-        thread — a server can warm before opening admission."""
+        thread — a server can warm before opening admission.  ``backends``
+        names, per topology, the engine its final stage ran on."""
         solved = hits = 0
-        for _name, factory in _registry_get(names):
+        backends: Dict[str, Optional[str]] = {}
+        for name, factory in _registry_get(names):
             problem = factory()
             sol = self._solve(problem, self._resolve_plan(plan), None, None)
             hits += int(sol.from_cache)
             solved += 1
+            backends[name] = next((st["backend"] for st in
+                                   reversed(sol.stage_stats)
+                                   if "backend" in st), None)
         with self._stats_lock:
             self.warmed += solved
-        return {"swept": solved, "already_cached": hits}
+        return {"swept": solved, "already_cached": hits,
+                "backends": backends}
 
     # -- solve path ----------------------------------------------------------
     def _resolve_plan(self, plan: Union[None, str, MappingPlan]) \

@@ -49,7 +49,6 @@ plan key (the server enforces this).
 from __future__ import annotations
 
 import math
-import multiprocessing
 import os
 import pickle
 import time
@@ -61,7 +60,7 @@ from ..core.cost_delta import IncrementalCost, PortfolioCost
 from ..core.grid import CartGrid
 from ..core.refine.engine import BoundaryController, RestartSeeder
 from ..core.refine.sharded import (ShardedPortfolioRefiner, _block_step,
-                                   _memo_table)
+                                   _memo_table, worker_context)
 from ..core.refine.portfolio import run_temperature
 from ..core.refine.swap import RefineResult
 from ..core.stencil import Stencil, resolve_weighted
@@ -182,16 +181,14 @@ class ShardWorkerPool:
     """Long-lived worker processes with per-worker pipes and measured byte
     accounting (``bytes_out`` / ``bytes_in`` count the exact framed pickle
     payloads).  Workers are daemonic (a dying server never strands them)
-    and numpy-only (fork-safe; jax is never touched in children).
+    and numpy-only (fork-safe; jax is never touched in children); they
+    start from :func:`~repro.core.refine.sharded.worker_context`.
     """
 
-    def __init__(self, workers: int = 2, start_method: Optional[str] = None):
+    def __init__(self, workers: int = 2):
         if int(workers) < 1:
             raise ValueError("workers must be >= 1")
-        if start_method is None:
-            methods = multiprocessing.get_all_start_methods()
-            start_method = "fork" if "fork" in methods else "spawn"
-        ctx = multiprocessing.get_context(start_method)
+        ctx = worker_context()
         self._procs = []
         self._conns = []
         for _ in range(int(workers)):

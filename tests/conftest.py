@@ -5,8 +5,6 @@ import sys
 os.environ.pop("XLA_FLAGS", None)
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
-# make tests/_hypothesis_compat.py importable under any pytest invocation
-sys.path.insert(0, os.path.dirname(__file__))
 
 import numpy as np
 import pytest

@@ -1,9 +1,6 @@
 """Cost function vs a brute-force oracle."""
 import numpy as np
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # offline: deterministic shim
-    from _hypothesis_compat import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import CartGrid, Stencil, evaluate
 from repro.core.cost import node_of_rank_blocked

@@ -4,10 +4,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 import pytest
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # offline: deterministic shim
-    from _hypothesis_compat import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import Stencil
 from repro.kernels.attention.ops import flash_attention
@@ -40,6 +37,31 @@ def test_stencil_kernel_sweep(dtype, hw, sname):
     u = jnp.asarray(rng.standard_normal((H + 2 * halo, W + 2 * halo)),
                     dtype=dtype)
     out = stencil_apply(u, offsets, weights, halo=halo, interpret=True)
+    ref = stencil_ref(u, offsets, weights, halo=halo)
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(ref, np.float32), atol=tol(dtype))
+
+
+@pytest.mark.parametrize("dtype", DTYPES)
+@pytest.mark.parametrize("hw,tile_rows", [((33, 40), 8), ((64, 24), 16),
+                                          ((50, 16), 32)])
+@pytest.mark.parametrize("sname", ["nn", "hops"])
+def test_stencil_kernel_row_panels(dtype, hw, tile_rows, sname):
+    """Several row panels per shard, each reading its halo rows from the
+    next panel's tail block, and a partial last panel — the tiling the
+    chip runs at 2048^2, at interpretable sizes."""
+    from repro.kernels.stencil.stencil import stencil_pallas
+    H, W = hw
+    st_obj = (Stencil.nearest_neighbor(2) if sname == "nn"
+              else Stencil.nn_with_hops(2))
+    offsets = st_obj.offsets
+    halo = int(np.abs(np.asarray(offsets)).max())
+    weights = tuple(1.0 / st_obj.k for _ in range(st_obj.k))
+    rng = np.random.default_rng(H * W)
+    u = jnp.asarray(rng.standard_normal((H + 2 * halo, W + 2 * halo)),
+                    dtype=dtype)
+    out = stencil_pallas(u, offsets, weights, halo, tile_rows=tile_rows,
+                         interpret=True)
     ref = stencil_ref(u, offsets, weights, halo=halo)
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(ref, np.float32), atol=tol(dtype))

@@ -8,10 +8,7 @@ vs annealed vs portfolio layouts monotonically with their J_max.
 import numpy as np
 import pytest
 
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # offline: deterministic shim
-    from _hypothesis_compat import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.analysis.linksim import (machine_for_nodes, replay_assignment,
                                     simulate, stencil_collectives)

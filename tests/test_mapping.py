@@ -18,10 +18,7 @@ import math
 
 import numpy as np
 import pytest
-try:
-    from hypothesis import assume, given, settings, strategies as st
-except ImportError:  # offline: deterministic shim
-    from _hypothesis_compat import assume, given, settings, strategies as st
+from hypothesis import assume, given, settings, strategies as st
 
 from repro.core import (CartGrid, MapperInapplicable, Stencil, dims_create,
                         evaluate, get_mapper)

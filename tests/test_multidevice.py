@@ -71,14 +71,8 @@ def test_real_sharded_execution_runs():
         from repro.sharding.partition import use_partitioning
         from jax.sharding import Mesh
 
-        # jax.sharding.AxisType only exists in jax >= 0.5; default axis
-        # semantics there are Auto, so the plain mesh is equivalent.
-        axis_type = getattr(jax.sharding, 'AxisType', None)
-        if axis_type is not None:
-            mesh = jax.make_mesh((4, 2), ('data', 'model'),
-                                 axis_types=(axis_type.Auto,) * 2)
-        else:
-            mesh = jax.make_mesh((4, 2), ('data', 'model'))
+        mesh = jax.make_mesh((4, 2), ('data', 'model'),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
         cfg = get_arch('granite-3-8b').reduced()
         shape = ShapeSpec('mini', seq_len=32, global_batch=8, kind='train')
         cell = build_cell(cfg, shape, mesh)
@@ -107,14 +101,9 @@ def test_halo_exchange_shard_map_matches_roll():
         import jax, numpy as np
         import jax.numpy as jnp
         from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
 
-        axis_type = getattr(jax.sharding, 'AxisType', None)
-        if axis_type is not None:
-            mesh = jax.make_mesh((8,), ('x',),
-                                 axis_types=(axis_type.Auto,))
-        else:
-            mesh = jax.make_mesh((8,), ('x',))
+        mesh = jax.make_mesh((8,), ('x',),
+                             axis_types=(jax.sharding.AxisType.Auto,))
         n = 64
         x = jnp.arange(n, dtype=jnp.float32)
 
@@ -125,7 +114,7 @@ def test_halo_exchange_shard_map_matches_roll():
                                      [(i, (i - 1) % 8) for i in range(8)])
             return left + right + 0 * u[:1]  # just prove neighbor data moves
 
-        f = shard_map(lambda u: jnp.concatenate(
+        f = jax.shard_map(lambda u: jnp.concatenate(
                 [jax.lax.ppermute(u[-1:], 'x', [(i, (i+1) % 8) for i in range(8)]),
                  u,
                  jax.lax.ppermute(u[:1], 'x', [(i, (i-1) % 8) for i in range(8)])]),

@@ -1,10 +1,7 @@
 """Executable NP-hardness reduction (paper §IV, Thm IV.3)."""
 import numpy as np
 import pytest
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # offline: deterministic shim
-    from _hypothesis_compat import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.core.cost import evaluate
 from repro.core.nphard import (assignment_from_3way, grid_partition_brute,

@@ -22,10 +22,7 @@ import time
 import numpy as np
 import pytest
 
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # offline: deterministic shim
-    from _hypothesis_compat import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import (CartGrid, MapperInapplicable, MappingPlan,
                         MappingProblem, PlanCache, Stencil, available_mappers,
@@ -323,14 +320,17 @@ def test_split_mapper_list_and_dryrun_order_suffix():
     order suffix never bites a signed bracket-option value."""
     import os
     from repro.core.mapping import split_mapper_list
-    saved = os.environ.get("XLA_FLAGS")         # dryrun import sets 512 fake
-    try:                                        # devices; don't leak it
+    # the dryrun import sets 512 fake devices and the CPU platform; don't
+    # leak either
+    saved = {k: os.environ.get(k) for k in ("XLA_FLAGS", "JAX_PLATFORMS")}
+    try:
         from repro.launch.dryrun import _split_order
     finally:
-        if saved is None:
-            os.environ.pop("XLA_FLAGS", None)
-        else:                                   # pragma: no cover
-            os.environ["XLA_FLAGS"] = saved
+        for k, v in saved.items():
+            if v is None:
+                os.environ.pop(k, None)
+            else:
+                os.environ[k] = v
     assert split_mapper_list(
         "blocked,portfolio[k=8,seed=3]:kdtree,hyperplane+rm") \
         == ["blocked", "portfolio[k=8,seed=3]:kdtree", "hyperplane+rm"]

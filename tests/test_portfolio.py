@@ -13,10 +13,7 @@ import time
 import numpy as np
 import pytest
 
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # offline: deterministic shim
-    from _hypothesis_compat import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import (CartGrid, IncrementalCost, PortfolioCost,
                         PortfolioRefiner, RefinedMapper, ScheduledRefiner,

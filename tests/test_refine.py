@@ -8,10 +8,7 @@ is the right assertion for unit weights.
 import numpy as np
 import pytest
 
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # offline: deterministic shim
-    from _hypothesis_compat import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import (CartGrid, IncrementalCost, MapperInapplicable,
                         RefinedMapper, Stencil, SwapRefiner, dims_create,

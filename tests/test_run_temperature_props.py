@@ -23,10 +23,7 @@ import copy
 
 import numpy as np
 
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # offline: deterministic shim
-    from _hypothesis_compat import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import CartGrid, PortfolioCost, Stencil
 from repro.core.refine.portfolio import run_temperature

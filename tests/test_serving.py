@@ -217,9 +217,12 @@ def test_server_warm_up_registry():
         (4, 4), Stencil.nearest_neighbor(2), (4, 4, 4, 4)))
     with PlanServer(threads=1, default_plan="refined:hyperplane") as srv:
         first = srv.warm_up(names=[name])
-        assert first == {"swept": 1, "already_cached": 0}
+        # refined: reports no engine backend of its own
+        assert first == {"swept": 1, "already_cached": 0,
+                         "backends": {name: None}}
         second = srv.warm_up(names=[name])
-        assert second == {"swept": 1, "already_cached": 1}
+        assert second == {"swept": 1, "already_cached": 1,
+                          "backends": {name: None}}
         t = srv.submit(mesh_shape=(4, 4), node_sizes=(4, 4, 4, 4))
         assert t.result(timeout=60).from_cache
         assert srv.stats()["warmed"] == 2

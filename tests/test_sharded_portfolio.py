@@ -11,10 +11,7 @@ kernel, same merge order), so any drift is a bug.
 import numpy as np
 import pytest
 
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # offline: deterministic shim
-    from _hypothesis_compat import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import (CartGrid, PlanCache, PortfolioCost, PortfolioRefiner,
                         RefinedMapper, ShardedPortfolioRefiner, Stencil,

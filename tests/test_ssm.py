@@ -5,10 +5,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # offline: deterministic shim
-    from _hypothesis_compat import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.configs import get_arch
 from repro.models.ssm import _ssd_chunked
@@ -57,10 +54,7 @@ def test_chunked_matches_naive(B, L, H, P, N, chunk):
 def test_chunked_matches_naive_quick():
     """Tier-1 stand-in for the slow property: two fixed shapes, one with a
     ragged final chunk, one chunk-aligned."""
-    inner = (getattr(test_chunked_matches_naive, "_shim_wrapped", None)
-             or getattr(getattr(test_chunked_matches_naive, "hypothesis",
-                                None), "inner_test", None))
-    assert inner is not None, "expected a @given-wrapped property"
+    inner = test_chunked_matches_naive.hypothesis.inner_test
     for B, L, H, P, N, chunk in [(1, 13, 2, 4, 3, 8), (2, 16, 1, 8, 4, 4)]:
         inner(B, L, H, P, N, chunk)
 

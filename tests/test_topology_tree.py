@@ -25,10 +25,7 @@ import math
 import numpy as np
 import pytest
 
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # offline: deterministic shim
-    from _hypothesis_compat import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import CartGrid, Stencil, evaluate
 from repro.core.refine import HierRefiner, hier_subtree_cache
