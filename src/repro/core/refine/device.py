@@ -65,11 +65,12 @@ from typing import List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from ... import obs
 from ..cost_delta import IncrementalCost, PortfolioCost
 from ..grid import CartGrid
 from ..stencil import Stencil, resolve_weighted
 from .engine import (BoundaryController, BoundaryReport, LadderEngine,
-                     RestartSeeder)
+                     RestartSeeder, phase_stats)
 from .portfolio import PortfolioRefiner
 from .sharded import _memo_table, stacked_crossing_counts
 from .swap import RefineResult
@@ -105,13 +106,16 @@ def _temperature_kernel(sa_moves: int):
     integer count delta applied on accept, energy
     ``d_J_max + d_J_sum * eps`` — plus device-side best-seen tracking.
     All :math:`O(rows \\cdot p)` state stays on device; only the boundary
-    report leaves.
+    report leaves.  The jitted function keeps the stable name
+    ``ladder_temperature_scan`` (module ``jit_ladder_temperature_scan``),
+    so a trace reduction can find it after a refactor.
     """
     import jax
     import jax.numpy as jnp
 
-    def run(node, cn, keys, best_node, best_jmax, best_jsum, done, live,
-            temps, eps, weights, out_valid, out_tgt, in_valid, in_src):
+    def ladder_temperature_scan(node, cn, keys, best_node, best_jmax,
+                                best_jsum, done, live, temps, eps, weights,
+                                out_valid, out_tgt, in_valid, in_src):
         R, p = node.shape
         N = cn.shape[1]
         k = cn.shape[2]
@@ -215,8 +219,7 @@ def _temperature_kernel(sa_moves: int):
         return (node, cn, keys, best_node, best_jmax, best_jsum, done,
                 acc, loads(cn).max(axis=1), off_sum(cn))
 
-    import jax
-    return jax.jit(run)
+    return jax.jit(ladder_temperature_scan)
 
 
 class DeviceLadderEngine(LadderEngine):
@@ -508,118 +511,145 @@ class DevicePortfolioRefiner:
         t0 = time.perf_counter()
         sched = self.schedule
         K = self.k
-        cur = np.asarray(node_of_pos, dtype=np.int64).copy()
-        initial = IncrementalCost(grid, stencil, cur, num_nodes=num_nodes,
-                                  weighted=sched.weighted).cost()
-        best, best_key = cur.copy(), (initial.j_max, initial.j_sum)
+        with obs.recording() as rec:
+            # 1. start key and the shared deterministic prefix
+            # (seed-independent, run once)
+            with obs.span("rounds"):
+                cur = np.asarray(node_of_pos, dtype=np.int64).copy()
+                initial = IncrementalCost(grid, stencil, cur,
+                                          num_nodes=num_nodes,
+                                          weighted=sched.weighted).cost()
+                best, best_key = cur.copy(), (initial.j_max, initial.j_sum)
 
-        def consider(candidate: np.ndarray, key: Tuple[float, float]):
-            nonlocal best, best_key
-            if key < best_key:
-                best, best_key = candidate.copy(), key
+                def consider(candidate: np.ndarray,
+                             key: Tuple[float, float]):
+                    nonlocal best, best_key
+                    if key < best_key:
+                        best, best_key = candidate.copy(), key
 
-        # 1. shared deterministic prefix (seed-independent, run once)
-        cur, swaps, passes = sched.run_rounds(grid, stencil, cur, num_nodes,
-                                              consider, max_swaps=None)
-        t_rounds = time.perf_counter() - t0
+                cur, swaps, passes = sched.run_rounds(
+                    grid, stencil, cur, num_nodes, consider, max_swaps=None)
 
-        # 2. device ladders under the shared boundary protocol
-        n_nodes = int(num_nodes) if num_nodes is not None \
-            else int(cur.max() + 1)
-        weights = (stencil.weight_array()
-                   if resolve_weighted(sched.weighted, stencil)
-                   else np.ones(stencil.k))
-        t_scale = float(np.mean(weights))
-        slots = self._resolved_slots()
-        factory = self.engine_factory or DeviceLadderEngine
-        eng = factory(grid, stencil, cur, self.seeds, num_nodes=n_nodes,
-                      weighted=sched.weighted, restart_slots=slots,
-                      counts_backend=self.counts_backend)
-        jmax0, jsum0 = eng.start_key
-        eps0 = float(1.0 / (1.0 + abs(jsum0)))
-        n_temps = len(sched.temperatures)
-        ctrl = BoundaryController(
-            k=K, kill_factor=self.kill_factor,
-            start_keys=np.asarray([jmax0, jsum0]),
-            restarts=self.restarts, retune=self.retune,
-            accept_band=self.accept_band, retune_bounds=self.retune_bounds,
-            sa_moves=sched.sa_moves, n_temps=n_temps,
-            seeder=RestartSeeder(self.seeds))
-        restarts: List[dict] = []
-        accepted = 0
-        rows = K + slots
-        cur_keys = np.broadcast_to(np.asarray([jmax0, jsum0]), (K, 2)).copy()
+            # 2. device ladders under the shared boundary protocol
+            with obs.span("ladders"):
+                n_nodes = int(num_nodes) if num_nodes is not None \
+                    else int(cur.max() + 1)
+                weights = (stencil.weight_array()
+                           if resolve_weighted(sched.weighted, stencil)
+                           else np.ones(stencil.k))
+                t_scale = float(np.mean(weights))
+                slots = self._resolved_slots()
+                factory = self.engine_factory or DeviceLadderEngine
+                with obs.span("engine_init"):
+                    eng = factory(grid, stencil, cur, self.seeds,
+                                  num_nodes=n_nodes, weighted=sched.weighted,
+                                  restart_slots=slots,
+                                  counts_backend=self.counts_backend)
+                jmax0, jsum0 = eng.start_key
+                eps0 = float(1.0 / (1.0 + abs(jsum0)))
+                n_temps = len(sched.temperatures)
+                ctrl = BoundaryController(
+                    k=K, kill_factor=self.kill_factor,
+                    start_keys=np.asarray([jmax0, jsum0]),
+                    restarts=self.restarts, retune=self.retune,
+                    accept_band=self.accept_band,
+                    retune_bounds=self.retune_bounds,
+                    sa_moves=sched.sa_moves, n_temps=n_temps,
+                    seeder=RestartSeeder(self.seeds))
+                restarts: List[dict] = []
+                accepted = 0
+                rows = K + slots
+                cur_keys = np.broadcast_to(np.asarray([jmax0, jsum0]),
+                                           (K, 2)).copy()
 
-        def leader() -> Tuple[np.ndarray, float]:
-            """Current portfolio leader (lexicographic best current key,
-            originals then restarts, lowest index wins ties) — one row
-            fetched from the device."""
-            cand = [((cur_keys[i, 0], cur_keys[i, 1], 0, i), i)
-                    for i in range(K) if ctrl.alive[i]]
-            cand += [((r["j_max"], r["j_sum"], 1, j), K + r["slot"])
-                     for j, r in enumerate(restarts)]
-            key, row = min(cand, key=lambda c: c[0])
-            return eng.row_state(row), float(key[1])
+                def leader() -> Tuple[np.ndarray, float]:
+                    """Current portfolio leader (lexicographic best current
+                    key, originals then restarts, lowest index wins ties)
+                    — one row fetched from the device."""
+                    cand = [((cur_keys[i, 0], cur_keys[i, 1], 0, i), i)
+                            for i in range(K) if ctrl.alive[i]]
+                    cand += [((r["j_max"], r["j_sum"], 1, j), K + r["slot"])
+                             for j, r in enumerate(restarts)]
+                    key, row = min(cand, key=lambda c: c[0])
+                    return eng.row_state(row), float(key[1])
 
-        def spawn(seed: int) -> bool:
-            node, lead_j_sum = leader()
-            slot = eng.spawn_restart(node, seed)
-            if slot is None:
-                return False
-            restarts.append({
-                "slot": slot, "seed": seed, "done": False,
-                "eps": float(1.0 / (1.0 + abs(lead_j_sum))),
-                "t_mult": 1.0,
-                "j_max": math.inf, "j_sum": math.inf,
-                "accepted_last": 0,
-            })
-            return True
+                def spawn(seed: int) -> bool:
+                    node, lead_j_sum = leader()
+                    slot = eng.spawn_restart(node, seed)
+                    if slot is None:
+                        return False
+                    restarts.append({
+                        "slot": slot, "seed": seed, "done": False,
+                        "eps": float(1.0 / (1.0 + abs(lead_j_sum))),
+                        "t_mult": 1.0,
+                        "j_max": math.inf, "j_sum": math.inf,
+                        "accepted_last": 0,
+                    })
+                    return True
 
-        for ti, T0 in enumerate(sched.temperatures):
-            T = max(T0 * t_scale, 1e-12)
-            temps = np.full(rows, T)
-            eps = np.full(rows, eps0)
-            for r in restarts:
-                temps[K + r["slot"]] = max(T0 * t_scale * r["t_mult"], 1e-12)
-                eps[K + r["slot"]] = r["eps"]
-            rep = eng.run_temperature(temps, sched.sa_moves, ctrl.alive, eps)
-            accepted += int(rep.accepted[:K].sum())
-            cur_keys = np.stack([rep.j_max[:K], rep.j_sum[:K]], axis=1)
-            for r in restarts:
-                row = K + r["slot"]
-                accepted += int(rep.accepted[row])
-                r.update(j_max=float(rep.j_max[row]),
-                         j_sum=float(rep.j_sum[row]),
-                         done=bool(rep.done[row]),
-                         accepted_last=int(rep.accepted[row]))
-            # the shared boundary protocol, one host round-trip per
-            # temperature: best-seen, kill (pushed back as the alive
-            # mask), pool accounting / retune / restart spawn
-            ctrl.update_best(cur_keys)
-            newly_killed = ctrl.kill()
-            eng.set_alive(ctrl.alive)
-            ctrl.adapt(ti, newly_killed, restarts, spawn)
-        t_ladders = time.perf_counter() - t0 - t_rounds
+                for ti, T0 in enumerate(sched.temperatures):
+                    with obs.span("temperature"):
+                        T = max(T0 * t_scale, 1e-12)
+                        temps = np.full(rows, T)
+                        eps = np.full(rows, eps0)
+                        for r in restarts:
+                            temps[K + r["slot"]] = max(
+                                T0 * t_scale * r["t_mult"], 1e-12)
+                            eps[K + r["slot"]] = r["eps"]
+                        rep = eng.run_temperature(temps, sched.sa_moves,
+                                                  ctrl.alive, eps)
+                        accepted += int(rep.accepted[:K].sum())
+                        cur_keys = np.stack([rep.j_max[:K], rep.j_sum[:K]],
+                                            axis=1)
+                        for r in restarts:
+                            row = K + r["slot"]
+                            accepted += int(rep.accepted[row])
+                            r.update(j_max=float(rep.j_max[row]),
+                                     j_sum=float(rep.j_sum[row]),
+                                     done=bool(rep.done[row]),
+                                     accepted_last=int(rep.accepted[row]))
+                        # the shared boundary protocol, one host round-trip
+                        # per temperature: best-seen, kill (pushed back as
+                        # the alive mask), pool accounting / retune /
+                        # restart spawn
+                        with obs.span("boundary"):
+                            ctrl.update_best(cur_keys)
+                            newly_killed = ctrl.kill()
+                            eng.set_alive(ctrl.alive)
+                            ctrl.adapt(ti, newly_killed, restarts, spawn)
 
-        # 3. survivors: end states AND device-tracked best-seen states are
-        # candidates; exact host keys come from the shared integer counts
-        # representation, then the single-process selection + polish
-        snap = eng.snapshot()
-        alive_rows = [i for i in range(K) if ctrl.alive[i]]
-        slot_rows = [K + r["slot"] for r in restarts]
-        pick = alive_rows + slot_rows
-        cand = np.concatenate([snap["nodes"][pick], snap["best_nodes"][pick]])
-        counts = stacked_crossing_counts(grid, stencil, cand, n_nodes,
-                                         use_jax=self.counts_backend)
-        cpc = PortfolioCost(grid, stencil, cand, num_nodes=n_nodes,
-                            weighted=sched.weighted,
-                            table=_memo_table(grid, stencil), counts=counts)
-        swaps, passes, polish_order = self.portfolio._polish_survivors(
-            grid, stencil, num_nodes, consider, cand, cpc.j_max(),
-            cpc.j_sum(), np.ones(cand.shape[0], dtype=bool), swaps, passes)
-
-        final = IncrementalCost(grid, stencil, best, num_nodes=num_nodes,
-                                weighted=sched.weighted).cost()
+            # 3. survivors: end states AND device-tracked best-seen states
+            # are candidates; exact host keys come from the shared integer
+            # counts representation, then the single-process selection +
+            # polish
+            with obs.span("survivors"):
+                with obs.span("snapshot"):
+                    snap = eng.snapshot()
+                with obs.span("rekey"):
+                    alive_rows = [i for i in range(K) if ctrl.alive[i]]
+                    slot_rows = [K + r["slot"] for r in restarts]
+                    pick = alive_rows + slot_rows
+                    cand = np.concatenate([snap["nodes"][pick],
+                                           snap["best_nodes"][pick]])
+                    counts = stacked_crossing_counts(
+                        grid, stencil, cand, n_nodes,
+                        use_jax=self.counts_backend)
+                    cpc = PortfolioCost(grid, stencil, cand,
+                                        num_nodes=n_nodes,
+                                        weighted=sched.weighted,
+                                        table=_memo_table(grid, stencil),
+                                        counts=counts)
+                with obs.span("polish"):
+                    swaps, passes, polish_order = \
+                        self.portfolio._polish_survivors(
+                            grid, stencil, num_nodes, consider, cand,
+                            cpc.j_max(), cpc.j_sum(),
+                            np.ones(cand.shape[0], dtype=bool), swaps,
+                            passes)
+                with obs.span("final"):
+                    final = IncrementalCost(grid, stencil, best,
+                                            num_nodes=num_nodes,
+                                            weighted=sched.weighted).cost()
         wall = time.perf_counter() - t0
         stats = {
             "k": self.k,
@@ -637,9 +667,7 @@ class DevicePortfolioRefiner:
             "pool_moves_left": ctrl.pool_moves,
             "polished": len(polish_order),
             "ladder_keys": [(float(j), float(s)) for j, s in cur_keys],
-            "t_rounds_s": t_rounds,
-            "t_ladders_s": t_ladders,
-            "t_polish_s": wall - t_rounds - t_ladders,
+            **phase_stats(rec, wall),
         }
         return RefineResult(assignment=best, initial=initial, final=final,
                             swaps=swaps, passes=passes, wall_time_s=wall,

@@ -33,7 +33,9 @@ This module is that protocol, factored once:
   agnostic;
 * :class:`RestartSeeder` — fresh restart seeds, guarded against colliding
   with user-supplied explicit ``seeds=`` lists (warn + shift, like the
-  portfolio's duplicate-seed dedupe).
+  portfolio's duplicate-seed dedupe);
+* :func:`phase_stats` — the engines' shared time split, read from the
+  solve's :mod:`repro.obs` recording.
 
 Float arithmetic order inside the controller is unchanged from the PR-3/5
 coordinators, so the refactor is bit-invisible to the engines' pinned
@@ -54,7 +56,7 @@ from ..grid import CartGrid
 from ..stencil import Stencil
 
 __all__ = ["BoundaryReport", "LadderEngine", "SerialLadderEngine",
-           "BoundaryController", "RestartSeeder"]
+           "BoundaryController", "RestartSeeder", "phase_stats"]
 
 
 @dataclass
@@ -255,3 +257,17 @@ class BoundaryController:
                 break
             self.pool_moves -= cost
             cap -= 1
+
+
+def phase_stats(rec: dict, wall: float) -> dict:
+    """A portfolio solve's time split, from its :func:`repro.obs.recording`
+    ``rec``, whose top-level spans are ``rounds`` (start key and the
+    deterministic rounds), ``ladders`` (engine set-up through the last
+    temperature boundary) and ``survivors``.  ``t_polish_s`` is the rest of
+    ``wall``: survivors' rekeying, polish and final key.  The recording's
+    spans and counters ride along."""
+    t_rounds = rec["spans"]["rounds"][1]
+    t_ladders = rec["spans"]["ladders"][1]
+    return {"t_rounds_s": t_rounds, "t_ladders_s": t_ladders,
+            "t_polish_s": wall - t_rounds - t_ladders,
+            "spans": rec["spans"], "counters": rec["counters"]}

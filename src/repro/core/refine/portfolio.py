@@ -46,9 +46,11 @@ from typing import Optional, Sequence, Tuple
 
 import numpy as np
 
+from ... import obs
 from ..cost_delta import IncrementalCost, PortfolioCost
 from ..grid import CartGrid
 from ..stencil import Stencil
+from .engine import phase_stats
 from .schedule import ScheduledRefiner
 from .swap import RefineResult
 
@@ -326,60 +328,74 @@ class PortfolioRefiner:
         historical engine, bit for bit."""
         t0 = time.perf_counter()
         sched = self.schedule
-        cur = np.asarray(node_of_pos, dtype=np.int64).copy()
         if pinned is not None:
             pinned = np.asarray(pinned, dtype=bool).reshape(-1)
             if pinned.shape[0] != grid.size:
                 raise ValueError(f"pinned mask has {pinned.shape[0]} "
                                  f"entries for a {grid.size}-position grid")
-        initial = IncrementalCost(grid, stencil, cur, num_nodes=num_nodes,
-                                  weighted=sched.weighted).cost()
-        best, best_key = cur.copy(), (initial.j_max, initial.j_sum)
+        with obs.recording() as rec:
+            # 1. start key and the shared deterministic prefix
+            # (seed-independent, run once; pin-oblivious, so the pinned
+            # path skips it)
+            with obs.span("rounds"):
+                cur = np.asarray(node_of_pos, dtype=np.int64).copy()
+                initial = IncrementalCost(grid, stencil, cur,
+                                          num_nodes=num_nodes,
+                                          weighted=sched.weighted).cost()
+                best, best_key = cur.copy(), (initial.j_max, initial.j_sum)
 
-        def consider(candidate: np.ndarray, key: Tuple[float, float]):
-            nonlocal best, best_key
-            if key < best_key:
-                best, best_key = candidate.copy(), key
+                def consider(candidate: np.ndarray,
+                             key: Tuple[float, float]):
+                    nonlocal best, best_key
+                    if key < best_key:
+                        best, best_key = candidate.copy(), key
 
-        # 1. shared deterministic prefix (seed-independent, run once;
-        # pin-oblivious, so the pinned path skips it)
-        if pinned is None:
-            cur, swaps, passes = sched.run_rounds(grid, stencil, cur,
-                                                  num_nodes, consider,
-                                                  max_swaps=self.max_swaps)
-        else:
-            swaps = passes = 0
-        t_rounds = time.perf_counter() - t0
+                if pinned is None:
+                    cur, swaps, passes = sched.run_rounds(
+                        grid, stencil, cur, num_nodes, consider,
+                        max_swaps=self.max_swaps)
+                else:
+                    swaps = passes = 0
 
-        # 2. K annealing ladders, batched (budget caps accepted moves at
-        # move granularity — up to K acceptances land per batched move)
-        budget = None if self.max_swaps is None else self.max_swaps - swaps
-        pc, alive, sa_accepted, killed = self._batched_ladders(
-            grid, stencil, cur, num_nodes, budget=budget,
-            allowed=None if pinned is None else ~pinned)
-        swaps += sa_accepted
-        t_ladders = time.perf_counter() - t0 - t_rounds
+            # 2. K annealing ladders, batched (budget caps accepted moves
+            # at move granularity — up to K acceptances land per batched
+            # move)
+            with obs.span("ladders"):
+                budget = None if self.max_swaps is None \
+                    else self.max_swaps - swaps
+                pc, alive, sa_accepted, killed = self._batched_ladders(
+                    grid, stencil, cur, num_nodes, budget=budget,
+                    allowed=None if pinned is None else ~pinned)
+                swaps += sa_accepted
 
-        # 3. raw survivors are free candidates; the best of them get the
-        # full polish phases (shared with the sharded engine's merge step)
-        # — pin-oblivious, so the pinned path takes raw survivors only
-        lad_j_max, lad_j_sum = pc.j_max(), pc.j_sum()
-        if pinned is None:
-            swaps, passes, polish_order = self._polish_survivors(
-                grid, stencil, num_nodes, consider, pc.node,
-                lad_j_max, lad_j_sum, alive, swaps, passes)
-        else:
-            K = pc.n_starts
-            for i in range(K):
-                if alive[i]:
-                    consider(pc.node[i].copy(),
-                             (float(lad_j_max[i]), float(lad_j_sum[i])))
-            polish_order = []
-            assert np.array_equal(best[pinned], node_of_pos[pinned]), \
-                "pinned positions moved (ladder mask violated)"
+            # 3. raw survivors are free candidates; the best of them get
+            # the full polish phases (shared with the sharded engine's
+            # merge step) — pin-oblivious, so the pinned path takes raw
+            # survivors only
+            with obs.span("survivors"):
+                lad_j_max, lad_j_sum = pc.j_max(), pc.j_sum()
+                if pinned is None:
+                    with obs.span("polish"):
+                        swaps, passes, polish_order = \
+                            self._polish_survivors(
+                                grid, stencil, num_nodes, consider, pc.node,
+                                lad_j_max, lad_j_sum, alive, swaps, passes)
+                else:
+                    K = pc.n_starts
+                    for i in range(K):
+                        if alive[i]:
+                            consider(pc.node[i].copy(),
+                                     (float(lad_j_max[i]),
+                                      float(lad_j_sum[i])))
+                    polish_order = []
+                    assert np.array_equal(best[pinned],
+                                          node_of_pos[pinned]), \
+                        "pinned positions moved (ladder mask violated)"
 
-        final = IncrementalCost(grid, stencil, best, num_nodes=num_nodes,
-                                weighted=sched.weighted).cost()
+                with obs.span("final"):
+                    final = IncrementalCost(grid, stencil, best,
+                                            num_nodes=num_nodes,
+                                            weighted=sched.weighted).cost()
         wall = time.perf_counter() - t0
         stats = {
             "k": self.k,
@@ -390,9 +406,7 @@ class PortfolioRefiner:
             "polished": len(polish_order),
             "ladder_keys": [(float(j), float(s)) for j, s in
                             zip(pc.j_max(), pc.j_sum())],
-            "t_rounds_s": t_rounds,
-            "t_ladders_s": t_ladders,
-            "t_polish_s": wall - t_rounds - t_ladders,
+            **phase_stats(rec, wall),
         }
         return RefineResult(assignment=best, initial=initial, final=final,
                             swaps=swaps, passes=passes, wall_time_s=wall,

@@ -74,11 +74,12 @@ from typing import List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from ... import obs
 from ..cost_delta import (IncrementalCost, NeighborTable, PortfolioCost,
                           stacked_count_arrays)
 from ..grid import CartGrid
 from ..stencil import Stencil, resolve_weighted
-from .engine import BoundaryController, RestartSeeder
+from .engine import BoundaryController, RestartSeeder, phase_stats
 from .portfolio import PortfolioRefiner, run_temperature
 from .swap import RefineResult
 
@@ -218,11 +219,13 @@ def _jit_stacked_counts(num_nodes: int):
     """Build (and cache) the jitted stacked-counts kernel for one node
     count.  ``num_segments`` must be static under jit; table arrays are
     traced arguments, so one cached callable serves every grid/stencil —
-    jax's own jit cache keys the shapes."""
+    jax's own jit cache keys the shapes.  The jitted function keeps the
+    stable name ``stacked_crossing_counts_one`` (module
+    ``jit_stacked_crossing_counts_one``) for trace reductions."""
     import jax
     import jax.numpy as jnp
 
-    def one(a, out_valid, out_tgt):                  # a: (p,)
+    def stacked_crossing_counts_one(a, out_valid, out_tgt):     # a: (p,)
         crossing = out_valid & (a[None, :] != a[out_tgt])        # (k, p)
         count_off = crossing.sum(axis=1)
         # count_node[j, n] = #{i : crossing[j, i] and a[i] == n}
@@ -231,7 +234,8 @@ def _jit_stacked_counts(num_nodes: int):
                                           num_segments=num_nodes))(crossing)
         return count_off, count_node                 # (k,), (k, N)
 
-    return jax.jit(jax.vmap(one, in_axes=(0, None, None)))
+    return jax.jit(jax.vmap(stacked_crossing_counts_one,
+                            in_axes=(0, None, None)))
 
 
 def _jax_stacked_counts(table: NeighborTable, A: np.ndarray,
@@ -474,59 +478,73 @@ class ShardedPortfolioRefiner:
             return res
         t0 = time.perf_counter()
         sched = self.schedule
-        cur = np.asarray(node_of_pos, dtype=np.int64).copy()
-        initial = IncrementalCost(grid, stencil, cur, num_nodes=num_nodes,
-                                  weighted=sched.weighted).cost()
-        best, best_key = cur.copy(), (initial.j_max, initial.j_sum)
+        with obs.recording() as rec:
+            # 1. start key and the shared deterministic prefix
+            # (seed-independent, run once)
+            with obs.span("rounds"):
+                cur = np.asarray(node_of_pos, dtype=np.int64).copy()
+                initial = IncrementalCost(grid, stencil, cur,
+                                          num_nodes=num_nodes,
+                                          weighted=sched.weighted).cost()
+                best, best_key = cur.copy(), (initial.j_max, initial.j_sum)
 
-        def consider(candidate: np.ndarray, key: Tuple[float, float]):
-            nonlocal best, best_key
-            if key < best_key:
-                best, best_key = candidate.copy(), key
+                def consider(candidate: np.ndarray,
+                             key: Tuple[float, float]):
+                    nonlocal best, best_key
+                    if key < best_key:
+                        best, best_key = candidate.copy(), key
 
-        # 1. shared deterministic prefix (seed-independent, run once)
-        cur, swaps, passes = sched.run_rounds(grid, stencil, cur, num_nodes,
-                                              consider, max_swaps=None)
-        t_rounds = time.perf_counter() - t0
+                cur, swaps, passes = sched.run_rounds(
+                    grid, stencil, cur, num_nodes, consider, max_swaps=None)
 
-        # 2. sharded ladders with coordinator-side boundaries
-        lad = self._sharded_ladders(grid, stencil, cur, num_nodes)
-        swaps += lad["sa_accepted"]
-        t_ladders = time.perf_counter() - t0 - t_rounds
+            # 2. sharded ladders with coordinator-side boundaries
+            with obs.span("ladders"):
+                lad = self._sharded_ladders(grid, stencil, cur, num_nodes)
+                swaps += lad["sa_accepted"]
 
-        # 3. original survivors: the exact single-process selection + polish
-        swaps, passes, polish_order = self.portfolio._polish_survivors(
-            grid, stencil, num_nodes, consider, lad["nodes"],
-            lad["lad_j_max"], lad["lad_j_sum"], lad["alive"], swaps, passes)
+            with obs.span("survivors"):
+                with obs.span("polish"):
+                    # 3. original survivors: the exact single-process
+                    # selection + polish
+                    swaps, passes, polish_order = \
+                        self.portfolio._polish_survivors(
+                            grid, stencil, num_nodes, consider, lad["nodes"],
+                            lad["lad_j_max"], lad["lad_j_sum"], lad["alive"],
+                            swaps, passes)
 
-        # 4. adaptive extras: restart ladders are pure additional
-        # candidates (raw + their own ranked polish), so the adaptive
-        # engine can only improve on the base portfolio's selection.
-        restart_polished = 0
-        restarts = lad["restarts"]
-        for r in restarts:
-            consider(r["node"].copy(), (r["j_max"], r["j_sum"]))
-        ranked = sorted(range(len(restarts)),
-                        key=lambda j: (restarts[j]["j_max"],
-                                       restarts[j]["j_sum"], j))
-        r_budget = len(ranked) if self.portfolio.polish_top is None \
-            else self.portfolio.polish_top
-        seen = set()
-        for j in ranked:
-            if restart_polished >= r_budget:
-                break
-            key = restarts[j]["node"].tobytes()
-            if key in seen:
-                continue
-            seen.add(key)
-            _, s, p = sched.polish(grid, stencil, restarts[j]["node"].copy(),
-                                   num_nodes, consider, max_swaps=None)
-            swaps += s
-            passes += p
-            restart_polished += 1
+                    # 4. adaptive extras: restart ladders are pure
+                    # additional candidates (raw + their own ranked
+                    # polish), so the adaptive engine can only improve on
+                    # the base portfolio's selection.
+                    restart_polished = 0
+                    restarts = lad["restarts"]
+                    for r in restarts:
+                        consider(r["node"].copy(), (r["j_max"], r["j_sum"]))
+                    ranked = sorted(range(len(restarts)),
+                                    key=lambda j: (restarts[j]["j_max"],
+                                                   restarts[j]["j_sum"], j))
+                    top = self.portfolio.polish_top
+                    r_budget = len(ranked) if top is None else top
+                    seen = set()
+                    for j in ranked:
+                        if restart_polished >= r_budget:
+                            break
+                        key = restarts[j]["node"].tobytes()
+                        if key in seen:
+                            continue
+                        seen.add(key)
+                        _, s, p = sched.polish(grid, stencil,
+                                               restarts[j]["node"].copy(),
+                                               num_nodes, consider,
+                                               max_swaps=None)
+                        swaps += s
+                        passes += p
+                        restart_polished += 1
 
-        final = IncrementalCost(grid, stencil, best, num_nodes=num_nodes,
-                                weighted=sched.weighted).cost()
+                with obs.span("final"):
+                    final = IncrementalCost(grid, stencil, best,
+                                            num_nodes=num_nodes,
+                                            weighted=sched.weighted).cost()
         wall = time.perf_counter() - t0
         stats = {
             "k": self.k,
@@ -543,9 +561,7 @@ class ShardedPortfolioRefiner:
             "restart_polished": restart_polished,
             "ladder_keys": [(float(j), float(s)) for j, s in
                             zip(lad["lad_j_max"], lad["lad_j_sum"])],
-            "t_rounds_s": t_rounds,
-            "t_ladders_s": t_ladders,
-            "t_polish_s": wall - t_rounds - t_ladders,
+            **phase_stats(rec, wall),
         }
         return RefineResult(assignment=best, initial=initial, final=final,
                             swaps=swaps, passes=passes, wall_time_s=wall,

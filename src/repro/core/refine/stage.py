@@ -66,9 +66,10 @@ _PLAIN_TYPES = (int, float, bool, str, type(None))
 
 #: refiner stats a refine stage passes through: the engine that ran
 #: (``device[tpu]``, ``host-fallback``, ``resident``, ...), the reason it
-#: delegated, and the device engine's time split
+#: delegated, the portfolio engines' time split, and the solve's
+#: :mod:`repro.obs` spans and counters
 _ENGINE_STATS = ("backend", "delegated", "t_rounds_s", "t_ladders_s",
-                 "t_polish_s")
+                 "t_polish_s", "spans", "counters")
 
 
 def _is_plain(v) -> bool:

@@ -17,6 +17,9 @@ Two engines implement the same search:
   first-improvement pass applies a maximal set of spatially-disjoint
   improving swaps per batch (positions whose neighbourhood an accepted
   swap touched are masked out, so every applied delta is still exact).
+  Each pass records the spans ``swap.score`` (frontier and gains) and
+  ``swap.apply``, and the counters ``swap.passes``, ``swap.pairs`` (pairs
+  scored) and ``swap.applied`` (see :mod:`repro.obs`).
 * ``engine="scalar"`` — the PR-1 per-vertex Python loop, kept as the
   bit-exact reference the batch engine is tested and benchmarked against.
 
@@ -34,6 +37,7 @@ from typing import Optional, Sequence, Tuple
 
 import numpy as np
 
+from ... import obs
 from ..cost import MappingCost
 from ..cost_delta import LOAD_CHUNK_ELEMS, IncrementalCost
 from ..grid import CartGrid
@@ -252,14 +256,18 @@ class SwapRefiner:
         """One whole-frontier batch, then apply the single best swap."""
         if swaps >= budget:
             return False, swaps
-        P, Q = self._frontier_pairs(ic)
-        if P.size == 0:
+        obs.count("swap.passes", 1)
+        with obs.span("swap.score"):
+            P, Q = self._frontier_pairs(ic)
+            if P.size:
+                gains, _, _ = self._batch_gains(ic, P, Q)
+                best = int(np.argmax(gains))
+        obs.count("swap.pairs", P.size)
+        if P.size == 0 or gains[best] <= self._tol(ic):
             return False, swaps
-        gains, _, _ = self._batch_gains(ic, P, Q)
-        best = int(np.argmax(gains))
-        if gains[best] <= self._tol(ic):
-            return False, swaps
-        ic.apply_swap(int(P[best]), int(Q[best]))
+        with obs.span("swap.apply"):
+            ic.apply_swap(int(P[best]), int(Q[best]))
+        obs.count("swap.applied", 1)
         return True, swaps + 1
 
     def _first_pass(self, ic: IncrementalCost, swaps: int,
@@ -277,38 +285,41 @@ class SwapRefiner:
         disjoint *node* load sets (two distant swaps may each keep the max
         at M while jointly pushing a shared node past it).
         """
-        P, Q = self._frontier_pairs(ic)
-        if P.size == 0:
+        obs.count("swap.passes", 1)
+        with obs.span("swap.score"):
+            P, Q = self._frontier_pairs(ic)
+            if P.size:
+                gains, strict, affected = self._batch_gains(
+                    ic, P, Q, need_affected=True)
+                improving = gains > self._tol(ic)
+                if strict is not None and bool(np.any(improving & strict)):
+                    improving &= strict
+                cand = np.nonzero(improving)[0]
+        obs.count("swap.pairs", P.size)
+        if P.size == 0 or cand.size == 0:
             return False, swaps
-        gains, strict, affected = self._batch_gains(ic, P, Q,
-                                                    need_affected=True)
-        improving = gains > self._tol(ic)
-        if strict is not None and bool(np.any(improving & strict)):
-            improving &= strict
-        cand = np.nonzero(improving)[0]
-        if cand.size == 0:
-            return False, swaps
-        dirty = np.zeros(ic.grid.size, dtype=bool)
-        dirty_nodes = np.zeros(ic.n_nodes, dtype=bool)
-        applied = False
-        for i in cand:
-            if swaps >= budget:
-                break
-            p, q = int(P[i]), int(Q[i])
-            if dirty[p] or dirty[q]:
-                continue
-            if affected is not None and bool(np.any(dirty_nodes
-                                                    & affected[i])):
-                continue
-            ic.apply_swap(p, q)
-            swaps += 1
-            applied = True
-            dirty[p] = dirty[q] = True
-            dirty[ic.neighbors_of(p)] = True
-            dirty[ic.neighbors_of(q)] = True
-            if affected is not None:
-                dirty_nodes |= affected[i]
-        return applied, swaps
+        start = swaps
+        with obs.span("swap.apply"):
+            dirty = np.zeros(ic.grid.size, dtype=bool)
+            dirty_nodes = np.zeros(ic.n_nodes, dtype=bool)
+            for i in cand:
+                if swaps >= budget:
+                    break
+                p, q = int(P[i]), int(Q[i])
+                if dirty[p] or dirty[q]:
+                    continue
+                if affected is not None and bool(np.any(dirty_nodes
+                                                        & affected[i])):
+                    continue
+                ic.apply_swap(p, q)
+                swaps += 1
+                dirty[p] = dirty[q] = True
+                dirty[ic.neighbors_of(p)] = True
+                dirty[ic.neighbors_of(q)] = True
+                if affected is not None:
+                    dirty_nodes |= affected[i]
+        obs.count("swap.applied", swaps - start)
+        return swaps > start, swaps
 
     # -- scalar reference engine (PR-1 loop) --------------------------------
     def _gain(self, ic: IncrementalCost, p: int, q: int) -> float:

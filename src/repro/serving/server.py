@@ -34,6 +34,7 @@ must be amortized against the application's communication volume.
 from __future__ import annotations
 
 import copy
+import itertools
 import math
 import queue
 import threading
@@ -43,6 +44,7 @@ from typing import Callable, Dict, List, Optional, Sequence, Tuple, Union
 
 import numpy as np
 
+from .. import obs
 from ..core.plan import (MappingPlan, MappingProblem, MappingSolution,
                          PlanCache, blocked_node_sizes, parse_plan,
                          _jsonable_stats)
@@ -57,6 +59,10 @@ __all__ = ["PlanServer", "PlanTicket", "AdmissionError",
 #: hyperplane base (the spelling is the cache identity — the resident
 #: engine serves it bit-identically to the stateless ``sharded:``).
 DEFAULT_SERVE_PLAN = "sharded[shards=2,k=8,restarts=auto]:hyperplane"
+
+#: completed requests whose latency and queue wait :meth:`PlanServer.stats`
+#: summarizes (a ring: the newest this many)
+STATS_WINDOW = 2048
 
 
 class AdmissionError(RuntimeError):
@@ -118,10 +124,14 @@ _register_defaults()
 
 
 class PlanTicket:
-    """Future-shaped handle for one submitted request."""
+    """Future-shaped handle for one submitted request.  ``started_at`` is
+    set when a serve thread takes it off the queue, and ``queue_wait_s``
+    is the time it waited there."""
 
     def __init__(self, deadline_s: Optional[float]):
         self.submitted_at = time.perf_counter()
+        self.started_at: Optional[float] = None
+        self.queue_wait_s: Optional[float] = None
         self.deadline_s = deadline_s
         self._event = threading.Event()
         self._solution: Optional[MappingSolution] = None
@@ -200,7 +210,9 @@ class PlanServer:
         self._pools_lock = threading.Lock()
         self._local = threading.local()
         self._stats_lock = threading.Lock()
-        self._latencies: deque = deque(maxlen=2048)
+        # (latency_s, queue_wait_s) of the newest completed requests
+        self._samples: deque = deque(maxlen=STATS_WINDOW)
+        self._seq = itertools.count()
         # single-flight: per-solution-key latch so concurrent cold misses
         # on one key run the solve once (followers wait, then hit cache)
         self._inflight_keys: Dict[str, threading.Event] = {}
@@ -514,46 +526,58 @@ class PlanServer:
                 req = self._queue.get(timeout=0.05)
             except queue.Empty:
                 continue
-            with self._stats_lock:
-                self.inflight += 1
             ticket = req.ticket
-            try:
-                if req.kind == "repair":
-                    from ..core.remap import repair_layout
-                    sol = repair_layout(req.args["previous"],
-                                        req.args["node_sizes"],
-                                        cache=self.cache,
-                                        **req.args["options"])
-                else:
-                    plan = self._resolve_plan(req.args["plan"])
-                    deadline_s = ticket.deadline_s
-                    if deadline_s is not None:
-                        # deadline is end-to-end: queue wait eats budget
-                        deadline_s = max(
-                            0.0, deadline_s - (time.perf_counter()
-                                               - ticket.submitted_at))
-                    sol = self._solve(req.args["problem"], plan,
-                                      deadline_s, ticket)
-                ticket._complete(sol, None)
-                with self._stats_lock:
-                    self.completed += 1
-                    self._latencies.append(ticket.latency_s)
-                    if ticket.deadline_missed:
-                        self.deadline_misses += 1
-            except BaseException as e:          # noqa: BLE001 - report all
-                ticket._complete(None, e)
-                with self._stats_lock:
-                    self.errors += 1
-            finally:
-                with self._stats_lock:
-                    self.inflight -= 1
+            ticket.started_at = time.perf_counter()
+            ticket.queue_wait_s = ticket.started_at - ticket.submitted_at
+            # the request's sequence number ties its spans together in a
+            # profiler trace
+            with obs.span("serve.request", request=next(self._seq)):
+                self._serve_one(req)
+
+    def _serve_one(self, req: _Request) -> None:
+        ticket = req.ticket
+        with self._stats_lock:
+            self.inflight += 1
+        try:
+            if req.kind == "repair":
+                from ..core.remap import repair_layout
+                sol = repair_layout(req.args["previous"],
+                                    req.args["node_sizes"],
+                                    cache=self.cache,
+                                    **req.args["options"])
+            else:
+                plan = self._resolve_plan(req.args["plan"])
+                deadline_s = ticket.deadline_s
+                if deadline_s is not None:
+                    # deadline is end-to-end: queue wait eats budget
+                    deadline_s = max(
+                        0.0, deadline_s - (time.perf_counter()
+                                           - ticket.submitted_at))
+                sol = self._solve(req.args["problem"], plan,
+                                  deadline_s, ticket)
+            ticket._complete(sol, None)
+            with self._stats_lock:
+                self.completed += 1
+                self._samples.append((ticket.latency_s,
+                                      ticket.queue_wait_s))
+                if ticket.deadline_missed:
+                    self.deadline_misses += 1
+        except BaseException as e:          # noqa: BLE001 - report all
+            ticket._complete(None, e)
+            with self._stats_lock:
+                self.errors += 1
+        finally:
+            with self._stats_lock:
+                self.inflight -= 1
 
     # -- observability -------------------------------------------------------
     def stats(self) -> dict:
         """Queue depth, throughput/latency, deadline and cache health —
-        the numbers the serving dashboard would scrape."""
+        the numbers the serving dashboard would scrape.  Latency and queue
+        wait percentiles cover the newest ``STATS_WINDOW`` completed
+        requests."""
         with self._stats_lock:
-            lats = sorted(self._latencies)
+            samples = list(self._samples)
             out = {
                 "queue_depth": self._queue.qsize(),
                 "inflight": self.inflight,
@@ -568,10 +592,13 @@ class PlanServer:
                 "uptime_s": (0.0 if self._started_at is None
                              else time.perf_counter() - self._started_at),
             }
-        if lats:
-            out["latency_p50_ms"] = 1e3 * lats[len(lats) // 2]
-            out["latency_p95_ms"] = 1e3 * lats[min(len(lats) - 1,
-                                                   int(0.95 * len(lats)))]
+        if samples:
+            for name, column in zip(("latency", "queue_wait"),
+                                    zip(*samples)):
+                v = sorted(column)
+                out[f"{name}_p50_ms"] = 1e3 * v[len(v) // 2]
+                out[f"{name}_p95_ms"] = 1e3 * v[min(len(v) - 1,
+                                                    int(0.95 * len(v)))]
         cs = self.cache.stats()
         looks = cs["hits"] + cs["misses"]
         out["cache"] = cs

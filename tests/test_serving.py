@@ -228,6 +228,46 @@ def test_server_warm_up_registry():
         assert srv.stats()["warmed"] == 2
 
 
+def test_queue_wait_is_at_most_latency_and_counts_a_wait_behind_a_slow_one():
+    srv = PlanServer(threads=1, shard_workers=1)
+    orig = srv._solve
+    slow = threading.Event()
+
+    def first_slow(*args, **kwargs):
+        if not slow.is_set():
+            slow.set()
+            time.sleep(0.3)
+        return orig(*args, **kwargs)
+
+    srv._solve = first_slow
+    problem = MappingProblem(DIMS, Stencil.nearest_neighbor(2), SIZES)
+    with srv:
+        t1 = srv.submit(problem, plan="blocked")
+        t2 = srv.submit(problem, plan="blocked")    # waits behind t1
+        t1.result(timeout=60)
+        t2.result(timeout=60)
+        for t in (t1, t2):
+            assert t.started_at >= t.submitted_at
+            assert 0.0 <= t.queue_wait_s <= t.latency_s
+        assert t2.queue_wait_s >= 0.2
+        st = srv.stats()
+        assert st["queue_wait_p95_ms"] >= 200.0
+        assert st["queue_wait_p50_ms"] <= st["latency_p50_ms"]
+
+
+def test_stats_ring_stays_bounded(monkeypatch):
+    import repro.serving.server as server_mod
+    monkeypatch.setattr(server_mod, "STATS_WINDOW", 4)
+    problem = MappingProblem(DIMS, Stencil.nearest_neighbor(2), SIZES)
+    with PlanServer(threads=1, shard_workers=1) as srv:
+        for _ in range(10):
+            srv.submit(problem, plan="blocked").result(timeout=60)
+        st = srv.stats()
+        assert st["completed"] == 10
+        assert len(srv._samples) == 4
+        assert "latency_p95_ms" in st and "queue_wait_p95_ms" in st
+
+
 # ---------------------------------------------------------------------------
 # anytime
 
