@@ -240,6 +240,11 @@ class IncrementalCost:
     def j_max(self) -> float:
         return float(self._per_node().max(initial=0.0))
 
+    @property
+    def count_node(self) -> np.ndarray:
+        """(N, k) int64 crossing edges per source node and offset."""
+        return self._count_node.copy()
+
     def cost(self) -> MappingCost:
         per_node = self.per_node
         bottleneck = int(per_node.argmax()) if self.n_nodes else 0

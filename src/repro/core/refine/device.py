@@ -27,7 +27,11 @@ This engine moves the *whole temperature* onto the accelerator:
 * each ladder additionally tracks its lexicographic **best-seen state on
   device** (the host engines only keep boundary keys), so at equal
   proposal budget the device portfolio's candidate set has up to 2K
-  entries — end states plus walk minima — before polish.
+  entries — end states plus walk minima — before polish;
+* the deterministic rounds and the survivors' polish run the host's swap
+  passes, but with integer weights their pairs are scored on the device
+  (:mod:`repro.core.refine.device_swap`): the same integer scores, so the
+  same swaps and the same layout as numpy scoring.
 
 Draw-for-draw parity with the numpy rng is not feasible (different
 generators), so the correctness contract is carried by
@@ -527,16 +531,22 @@ class DevicePortfolioRefiner:
                     if key < best_key:
                         best, best_key = candidate.copy(), key
 
-                cur, swaps, passes = sched.run_rounds(
-                    grid, stencil, cur, num_nodes, consider, max_swaps=None)
-
-            # 2. device ladders under the shared boundary protocol
-            with obs.span("ladders"):
                 n_nodes = int(num_nodes) if num_nodes is not None \
                     else int(cur.max() + 1)
                 weights = (stencil.weight_array()
                            if resolve_weighted(sched.weighted, stencil)
                            else np.ones(stencil.k))
+                # the rounds' and the polish's swap pairs are scored on the
+                # device where that is exact (integer weights), else numpy
+                from . import device_swap
+                scorer = device_swap.device_swap_scorer(grid, stencil,
+                                                        weights)
+                cur, swaps, passes = sched.run_rounds(
+                    grid, stencil, cur, num_nodes, consider, max_swaps=None,
+                    scorer=scorer)
+
+            # 2. device ladders under the shared boundary protocol
+            with obs.span("ladders"):
                 t_scale = float(np.mean(weights))
                 slots = self._resolved_slots()
                 factory = self.engine_factory or DeviceLadderEngine
@@ -645,7 +655,7 @@ class DevicePortfolioRefiner:
                             grid, stencil, num_nodes, consider, cand,
                             cpc.j_max(), cpc.j_sum(),
                             np.ones(cand.shape[0], dtype=bool), swaps,
-                            passes)
+                            passes, scorer=scorer)
                 with obs.span("final"):
                     final = IncrementalCost(grid, stencil, best,
                                             num_nodes=num_nodes,

@@ -279,14 +279,15 @@ class PortfolioRefiner:
                           num_nodes: Optional[int], consider,
                           nodes: np.ndarray, lad_j_max: np.ndarray,
                           lad_j_sum: np.ndarray, alive: np.ndarray,
-                          swaps: int, passes: int):
+                          swaps: int, passes: int, scorer=None):
         """Feed every surviving raw ladder state to ``consider`` (its exact
         key is already on hand, so it is a candidate for free), then run the
         full polish phases on the most promising survivors: start 0 always
         (the dominance guarantee vs the single annealed run), then the best
         survivors by ladder-end key, deduplicating identical end states.
-        ``nodes`` is the (K, p) ladder-end assignment stack.  Returns the
-        updated ``(swaps, passes, polish_order)``."""
+        ``nodes`` is the (K, p) ladder-end assignment stack; ``scorer``
+        goes to the polish phases (only the device engine passes one).
+        Returns the updated ``(swaps, passes, polish_order)``."""
         sched = self.schedule
         K = nodes.shape[0]
         for i in range(K):
@@ -309,7 +310,7 @@ class PortfolioRefiner:
             cap = None if self.max_swaps is None \
                 else max(0, self.max_swaps - swaps)
             _, s, p = sched.polish(grid, stencil, nodes[i].copy(), num_nodes,
-                                   consider, max_swaps=cap)
+                                   consider, max_swaps=cap, scorer=scorer)
             swaps += s
             passes += p
         return swaps, passes, polish_order

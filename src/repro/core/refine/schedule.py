@@ -115,12 +115,13 @@ class ScheduledRefiner:
                 "max_swaps": self.max_swaps}
 
     # -- phases -------------------------------------------------------------
-    def _phase(self, objective: str,
-               max_swaps: Optional[int] = None) -> SwapRefiner:
+    def _phase(self, objective: str, max_swaps: Optional[int] = None,
+               scorer=None) -> SwapRefiner:
         return SwapRefiner(objective=objective, policy=self.policy,
                            max_passes=self.max_passes, weighted=self.weighted,
                            tol=self.tol, max_partners=self.max_partners,
-                           engine=self.engine, max_swaps=max_swaps)
+                           engine=self.engine, max_swaps=max_swaps,
+                           scorer=scorer)
 
     def _sa_ladder(self, grid: CartGrid, stencil: Stencil,
                    assignment: np.ndarray, num_nodes: Optional[int],
@@ -163,20 +164,21 @@ class ScheduledRefiner:
     # -- schedule building blocks (shared with PortfolioRefiner) ------------
     def run_rounds(self, grid: CartGrid, stencil: Stencil, cur: np.ndarray,
                    num_nodes: Optional[int], consider,
-                   max_swaps: Optional[int] = None) \
+                   max_swaps: Optional[int] = None, scorer=None) \
             -> Tuple[np.ndarray, int, int]:
         """The deterministic alternating-objective rounds: returns the final
         phase-chain state (the SA ladder's start point — *not* the
         lexicographic best) plus accepted-swap/pass counts.  ``consider`` is
         called with every phase result's ``(assignment, (j_max, j_sum))``;
-        ``max_swaps`` caps total accepted swaps across all phases."""
+        ``max_swaps`` caps total accepted swaps across all phases;
+        ``scorer`` goes to every phase's :class:`SwapRefiner`."""
         swaps = passes = 0
         for _ in range(self.rounds):
             round_swaps = 0
             for obj in self.objectives:
                 cap = None if max_swaps is None else max_swaps - swaps
-                res = self._phase(obj, cap).refine(grid, stencil, cur,
-                                                   num_nodes=num_nodes)
+                res = self._phase(obj, cap, scorer).refine(
+                    grid, stencil, cur, num_nodes=num_nodes)
                 cur = res.assignment
                 swaps += res.swaps
                 passes += res.passes
@@ -190,15 +192,15 @@ class ScheduledRefiner:
 
     def polish(self, grid: CartGrid, stencil: Stencil, cur: np.ndarray,
                num_nodes: Optional[int], consider,
-               max_swaps: Optional[int] = None) \
+               max_swaps: Optional[int] = None, scorer=None) \
             -> Tuple[np.ndarray, int, int]:
         """One pass of the phase objectives over a (perturbed) state — what
         the annealed schedule runs after its SA ladder."""
         swaps = passes = 0
         for obj in self.objectives:
             cap = None if max_swaps is None else max_swaps - swaps
-            res = self._phase(obj, cap).refine(grid, stencil, cur,
-                                               num_nodes=num_nodes)
+            res = self._phase(obj, cap, scorer).refine(grid, stencil, cur,
+                                                       num_nodes=num_nodes)
             cur = res.assignment
             swaps += res.swaps
             passes += res.passes

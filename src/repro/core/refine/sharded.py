@@ -273,12 +273,16 @@ _TABLE_MEMO: "OrderedDict[tuple, NeighborTable]" = OrderedDict()
 _TABLE_MEMO_MAX = 8
 
 
-def _memo_table(grid: CartGrid, stencil: Stencil) -> NeighborTable:
+def _table_key(grid: CartGrid, stencil: Stencil) -> tuple:
     # cache_token keeps graph-backed and masked grids from colliding with
     # a plain CartGrid of the same dims (they answer shift_ranks
     # differently, so sharing a table would be silently wrong).
-    key = (tuple(grid.dims), tuple(grid.periodic),
-           getattr(grid, "cache_token", ""), stencil.offsets)
+    return (tuple(grid.dims), tuple(grid.periodic),
+            getattr(grid, "cache_token", ""), stencil.offsets)
+
+
+def _memo_table(grid: CartGrid, stencil: Stencil) -> NeighborTable:
+    key = _table_key(grid, stencil)
     table = _TABLE_MEMO.get(key)
     if table is None:
         table = NeighborTable.build(grid, stencil)

@@ -19,7 +19,11 @@ Two engines implement the same search:
   swap touched are masked out, so every applied delta is still exact).
   Each pass records the spans ``swap.score`` (frontier and gains) and
   ``swap.apply``, and the counters ``swap.passes``, ``swap.pairs`` (pairs
-  scored) and ``swap.applied`` (see :mod:`repro.obs`).
+  scored) and ``swap.applied`` (see :mod:`repro.obs`).  Given a device
+  scorer (:mod:`repro.core.refine.device_swap`, which only the device
+  portfolio passes), the pairs are scored on the accelerator instead,
+  with the same integer values, and each pass adds its pairs to
+  ``swap.device_pairs`` as well.
 * ``engine="scalar"`` — the PR-1 per-vertex Python loop, kept as the
   bit-exact reference the batch engine is tested and benchmarked against.
 
@@ -104,12 +108,16 @@ class SwapRefiner:
         between cells that are not stencil neighbours of each other.
       engine: "batch" (vectorized frontier scoring) or "scalar" (PR-1
         reference loop).
+      scorer: internal; a :class:`~repro.core.refine.device_swap.DeviceSwapScorer`
+        that scores the batch engine's pairs on the accelerator (None:
+        numpy).  It changes no result, so it is not part of ``config()``.
     """
 
     def __init__(self, objective: str = "j_sum", policy: str = "first",
                  max_passes: int = 8, max_swaps: Optional[int] = None,
                  weighted="auto", tol: float = 1e-12,
-                 max_partners: int = 32, engine: str = "batch"):
+                 max_partners: int = 32, engine: str = "batch",
+                 scorer=None):
         if objective not in _OBJECTIVES:
             raise ValueError(f"objective must be one of {_OBJECTIVES}")
         if policy not in _POLICIES:
@@ -126,6 +134,7 @@ class SwapRefiner:
         self.tol = float(tol)
         self.max_partners = int(max_partners)
         self.engine = engine
+        self.scorer = scorer
 
     def as_stage(self, budget: Optional[int] = None):
         """Uniform :class:`~repro.core.refine.stage.RefineStage` adapter
@@ -221,35 +230,56 @@ class SwapRefiner:
         codes = np.unique(np.concatenate([adj_codes, far_codes]))
         return codes // size, codes % size
 
-    def _batch_gains(self, ic: IncrementalCost, P: np.ndarray, Q: np.ndarray,
-                     need_affected: bool = False) \
-            -> Tuple[np.ndarray, Optional[np.ndarray], Optional[np.ndarray]]:
-        """Per-pair gain of the configured objective (positive = improving).
-        For j_max also returns the strict-improvement mask (gains driven by
-        a real bottleneck drop rather than the J_sum tie-break) and, when
-        ``need_affected`` (first-improvement's disjointness guard), the
-        (m, N) bool mask of nodes whose load each swap would change."""
+    def _pair_deltas(self, ic: IncrementalCost, P: np.ndarray,
+                     Q: np.ndarray) -> Tuple[np.ndarray, Optional[np.ndarray]]:
+        """Per-pair ``d_j_sum`` and, for j_max, ``new_j_max`` (float64),
+        from the device scorer when there is one, else from numpy."""
+        if self.scorer is not None:
+            d_j_sum, new_j_max = self.scorer.score(ic, P, Q)
+            return d_j_sum.astype(np.float64), new_j_max.astype(np.float64)
         if self.objective == "j_sum":
-            bd = ic.batch_swap_deltas(P, Q)
-            return -bd.d_j_sum, None, None
+            return ic.batch_swap_deltas(P, Q).d_j_sum, None
         # j_max scoring needs (m, N) load matrices; chunk so peak memory is
         # bounded no matter how large the frontier is.
-        per_node, j_max_now, m = ic.per_node, ic.j_max, P.size
+        m = P.size
         chunk = max(1, _LOAD_CHUNK_ELEMS // max(1, ic.n_nodes))
-        gains = np.empty(m, dtype=np.float64)
-        strict = np.empty(m, dtype=bool)
-        affected = (np.empty((m, ic.n_nodes), dtype=bool)
-                    if need_affected else None)
+        d_j_sum = np.empty(m, dtype=np.float64)
+        new_j_max = np.empty(m, dtype=np.float64)
         for s in range(0, m, chunk):
             e = min(s + chunk, m)
             bd = ic.batch_swap_deltas(P[s:e], Q[s:e], with_loads=True)
-            primary = j_max_now - bd.new_j_max
-            tie = np.where(bd.d_j_sum < 0, -bd.d_j_sum * 1e-9, 0.0)
-            gains[s:e] = np.where(primary != 0.0, primary, tie)
-            strict[s:e] = primary > 0.0
-            if need_affected:
-                affected[s:e] = bd.new_per_node != per_node[None, :]
-        return gains, strict, affected
+            d_j_sum[s:e], new_j_max[s:e] = bd.d_j_sum, bd.new_j_max
+        return d_j_sum, new_j_max
+
+    def _batch_gains(self, ic: IncrementalCost, P: np.ndarray,
+                     Q: np.ndarray) -> Tuple[np.ndarray, Optional[np.ndarray]]:
+        """Per-pair gain of the configured objective (positive = improving).
+        For j_max also returns the strict-improvement mask (gains driven by
+        a real bottleneck drop rather than the J_sum tie-break)."""
+        d_j_sum, new_j_max = self._pair_deltas(ic, P, Q)
+        if self.objective == "j_sum":
+            return -d_j_sum, None
+        primary = ic.j_max - new_j_max
+        tie = np.where(d_j_sum < 0, -d_j_sum * 1e-9, 0.0)
+        return np.where(primary != 0.0, primary, tie), primary > 0.0
+
+    @staticmethod
+    def _affected(ic: IncrementalCost, P: np.ndarray,
+                  Q: np.ndarray) -> np.ndarray:
+        """(m, N) bool: the nodes whose load each swap would change
+        (first-improvement's disjointness guard under j_max), chunked like
+        the scoring."""
+        per_node = ic.per_node
+        chunk = max(1, _LOAD_CHUNK_ELEMS // max(1, ic.n_nodes))
+        return np.concatenate([
+            ic.batch_swap_deltas(P[s:s + chunk], Q[s:s + chunk],
+                                 with_loads=True).new_per_node
+            != per_node[None, :] for s in range(0, P.size, chunk)])
+
+    def _count_pairs(self, m: int) -> None:
+        obs.count("swap.pairs", m)
+        if self.scorer is not None:
+            obs.count("swap.device_pairs", m)
 
     def _steepest_pass(self, ic: IncrementalCost, swaps: int,
                        budget: float) -> Tuple[bool, int]:
@@ -260,9 +290,9 @@ class SwapRefiner:
         with obs.span("swap.score"):
             P, Q = self._frontier_pairs(ic)
             if P.size:
-                gains, _, _ = self._batch_gains(ic, P, Q)
+                gains, _ = self._batch_gains(ic, P, Q)
                 best = int(np.argmax(gains))
-        obs.count("swap.pairs", P.size)
+        self._count_pairs(P.size)
         if P.size == 0 or gains[best] <= self._tol(ic):
             return False, swaps
         with obs.span("swap.apply"):
@@ -289,27 +319,29 @@ class SwapRefiner:
         with obs.span("swap.score"):
             P, Q = self._frontier_pairs(ic)
             if P.size:
-                gains, strict, affected = self._batch_gains(
-                    ic, P, Q, need_affected=True)
+                gains, strict = self._batch_gains(ic, P, Q)
                 improving = gains > self._tol(ic)
                 if strict is not None and bool(np.any(improving & strict)):
                     improving &= strict
                 cand = np.nonzero(improving)[0]
-        obs.count("swap.pairs", P.size)
+                # row r of `affected` belongs to pair cand[r]
+                affected = (self._affected(ic, P[cand], Q[cand])
+                            if strict is not None and cand.size else None)
+        self._count_pairs(P.size)
         if P.size == 0 or cand.size == 0:
             return False, swaps
         start = swaps
         with obs.span("swap.apply"):
             dirty = np.zeros(ic.grid.size, dtype=bool)
             dirty_nodes = np.zeros(ic.n_nodes, dtype=bool)
-            for i in cand:
+            for r, i in enumerate(cand):
                 if swaps >= budget:
                     break
                 p, q = int(P[i]), int(Q[i])
                 if dirty[p] or dirty[q]:
                     continue
                 if affected is not None and bool(np.any(dirty_nodes
-                                                        & affected[i])):
+                                                        & affected[r])):
                     continue
                 ic.apply_swap(p, q)
                 swaps += 1
@@ -317,7 +349,7 @@ class SwapRefiner:
                 dirty[ic.neighbors_of(p)] = True
                 dirty[ic.neighbors_of(q)] = True
                 if affected is not None:
-                    dirty_nodes |= affected[i]
+                    dirty_nodes |= affected[r]
         obs.count("swap.applied", swaps - start)
         return swaps > start, swaps
 

@@ -324,3 +324,99 @@ def test_device_restarts_spawn_from_pool():
     assert not set(res.stats["restart_seeds"]) & set(res.stats["seeds"])
     assert (res.final.j_max, res.final.j_sum) \
         <= (res.initial.j_max, res.initial.j_sum)
+
+
+# ---------------------------------------------------------------------------
+# the device swap scorer: same swaps and layouts as numpy scoring
+
+
+@pytest.mark.parametrize("dims,n_nodes", [((9, 8), 6), ((4, 5, 4), 5)],
+                         ids=["2d", "3d"])
+def test_device_scorer_serves_the_numpy_layout(monkeypatch, dims, n_nodes):
+    """With unit weights the rounds' and the polish's pairs are scored on
+    the device (told here that the CPU is an accelerator); forcing numpy
+    scoring (the module's factory returns no scorer) gives the same
+    layout, swaps, passes and pass counters."""
+    from repro.core.refine import device_swap
+    monkeypatch.setattr(device_swap, "_accelerator", lambda: True)
+    grid = CartGrid(dims)
+    st_ = Stencil.nearest_neighbor(len(dims))
+    start = np.random.default_rng(17).permutation(
+        np.arange(grid.size) % n_nodes)
+
+    def solve():
+        return DevicePortfolioRefiner(k=4, sa_moves=30, restarts="auto",
+                                      seed=3).refine(grid, st_, start,
+                                                     num_nodes=n_nodes)
+    dev = solve()
+    monkeypatch.setattr(device_swap, "device_swap_scorer",
+                        lambda *a, **k: None)
+    host = solve()
+    assert dev.assignment.tobytes() == host.assignment.tobytes()
+    assert (dev.swaps, dev.passes) == (host.swaps, host.passes)
+    dc, hc = dev.stats["counters"], host.stats["counters"]
+    for phase in ("rounds", "survivors/polish"):
+        for name in ("swap.passes", "swap.pairs", "swap.applied"):
+            assert dc[f"{phase}/{name}"] == hc[f"{phase}/{name}"]
+        assert dc[f"{phase}/swap.device_pairs"] == dc[f"{phase}/swap.pairs"]
+        assert f"{phase}/swap.device_pairs" not in hc
+
+
+def test_scorer_builds_one_program_for_frontiers_of_every_size(
+        monkeypatch):
+    """Passes over frontiers of different sizes, some over one chunk,
+    run one compiled program for the problem."""
+    from repro.core.refine import device_swap
+    from repro.core.refine.device_swap import device_swap_scorer
+    from repro.core.refine.swap import SwapRefiner
+    monkeypatch.setattr(device_swap, "_accelerator", lambda: True)
+    grid = CartGrid((11, 7))
+    st_ = Stencil.nearest_neighbor(2)
+    start = np.random.default_rng(2).permutation(np.arange(77) % 5)
+    scorer = device_swap_scorer(grid, st_, np.ones(st_.k), chunk=72)
+    sizes = []
+    score = scorer.score
+
+    def counted(ic, P, Q):
+        sizes.append(P.size)
+        return score(ic, P, Q)
+    scorer.score = counted
+    built = []
+
+    def on_compile(event, duration, **_):
+        if event == "/jax/core/compile/backend_compile_duration":
+            built.append(duration)
+    jax.monitoring.register_event_duration_secs_listener(on_compile)
+    try:
+        for objective in ("j_sum", "j_max"):
+            SwapRefiner(objective=objective, scorer=scorer).refine(
+                grid, st_, start, num_nodes=5)
+    finally:
+        jax.monitoring.unregister_event_duration_listener(on_compile)
+    assert len(set(sizes)) > 2 and max(sizes) > 72
+    assert len(built) == 1
+
+
+def test_other_refiners_never_import_the_scorer():
+    """``refined:``, ``refined2:``, ``annealed:``, ``portfolio:``,
+    ``sharded:`` and ``hier:`` score with numpy: a fresh process that runs
+    them all never loads the device scorer's module."""
+    import os
+    import subprocess
+    import sys
+    from pathlib import Path
+    env = dict(os.environ,
+               PYTHONPATH=str(Path(__file__).resolve().parents[1] / "src"))
+    code = (
+        "import sys\n"
+        "from repro.core import CartGrid, Stencil, get_mapper\n"
+        "g, st = CartGrid((6, 8)), Stencil.nearest_neighbor(2)\n"
+        "for s in ['refined:hyperplane', 'refined2:hyperplane',\n"
+        "          'annealed:hyperplane', 'portfolio[k=2]:hyperplane',\n"
+        "          'sharded[k=2,shards=2]:hyperplane', 'hier:hyperplane']:\n"
+        "    get_mapper(s).assignment(g, st, [16, 16, 16])\n"
+        "print('repro.core.refine.device_swap' in sys.modules)\n")
+    out = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                         text=True, env=env, timeout=300)
+    assert out.returncode == 0, out.stderr[-3000:]
+    assert out.stdout.strip().splitlines()[-1] == "False"

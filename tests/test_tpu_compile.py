@@ -21,6 +21,7 @@ from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
 from repro.core import CartGrid, Stencil, cart_create
 from repro.core.remap import apply_layout
 from repro.core.refine.device import _temperature_kernel
+from repro.core.refine.device_swap import CHUNK, _scores_kernel
 from repro.core.refine.sharded import _jit_stacked_counts, _memo_table
 from repro.kernels.stencil.ops import stencil_apply
 from repro.kernels.stencil.jacobi import jacobi_sweeps, jacobi_taps
@@ -121,6 +122,21 @@ def test_stacked_counts_compile_for_v5e(one_chip):
         _shape((k, p), jnp.bool_, one_chip),
         _shape((k, p), jnp.int32, one_chip)).compile()
     assert compiled.memory_analysis().temp_size_in_bytes < HBM_BYTES
+
+
+@pytest.mark.parametrize("dims", [(32, 31), (31, 8, 4)], ids=["2d", "3d"])
+def test_swap_scorer_compiles_for_v5e(one_chip, dims):
+    """The polish's device swap scorer, one chunk of pairs, at the size of
+    the paper's largest Fig. 8 instances (992 processes on 31 nodes)."""
+    p, N, k = int(np.prod(dims)), 31, 2 * len(dims)
+    i32, b = jnp.int32, jnp.bool_
+    compiled = _scores_kernel().lower(
+        _shape((p,), i32, one_chip), _shape((N, k), i32, one_chip),
+        _shape((2, CHUNK), i32, one_chip), _shape((k,), i32, one_chip),
+        _shape((k, p), b, one_chip), _shape((k, p), i32, one_chip),
+        _shape((k, p), b, one_chip), _shape((k, p), i32, one_chip)).compile()
+    mem = compiled.memory_analysis()
+    assert 0 < mem.temp_size_in_bytes < HBM_BYTES
 
 
 @pytest.mark.parametrize("plan", ["hyperplane", "stencil_strips"])
