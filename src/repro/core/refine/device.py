@@ -24,6 +24,12 @@ This engine moves the *whole temperature* onto the accelerator:
   (:class:`~repro.core.refine.engine.BoundaryController`: best-seen,
   early-kill, restart/retune) runs on the coordinator exactly as it does
   for the serial and sharded engines;
+* a ladder within one swap of its start at a temperature boundary (at
+  most two positions differ) runs its next temperature at the schedule's
+  first (hottest) one, so a ladder ends where it started only by undoing
+  two or more swaps in its last temperature (the host engines have no
+  such rule; from a start where few swaps raise J_max, as on the 2-D
+  Fig. 8 instance, it never fires);
 * each ladder additionally tracks its lexicographic **best-seen state on
   device** (the host engines only keep boundary keys), so at equal
   proposal budget the device portfolio's candidate set has up to 2K
@@ -226,6 +232,18 @@ def _temperature_kernel(sa_moves: int):
     return jax.jit(ladder_temperature_scan)
 
 
+@functools.lru_cache(maxsize=1)
+def _moved_kernel():
+    """Jitted ``(rows, p), (p,) -> (rows,)`` count of positions where each
+    row differs from the start."""
+    import jax
+
+    def moved_positions(node, start):
+        return (node != start[None, :]).sum(axis=1)
+
+    return jax.jit(moved_positions)
+
+
 class DeviceLadderEngine(LadderEngine):
     """K + ``restart_slots`` annealing ladders resident on the accelerator.
 
@@ -268,6 +286,7 @@ class DeviceLadderEngine(LadderEngine):
             per0 += weights[j] * cn0[0, :, j]
             jsum0 += float(weights[j]) * float(co0[0, j])
         self.start_key = (float(per0.max(initial=0.0)), float(jsum0))
+        self._start = jnp.asarray(A[0], jnp.int32)
         self._node = jnp.asarray(np.broadcast_to(A, (R, p)), jnp.int32)
         self._cn = jnp.asarray(
             np.broadcast_to(cn0, (R, N, stencil.k)), jnp.int32)
@@ -328,6 +347,11 @@ class DeviceLadderEngine(LadderEngine):
         """One row's current assignment (host copy) — the leader fetch the
         restart spawn path needs."""
         return np.asarray(self._node[int(r)], dtype=np.int64)
+
+    def moved_positions(self) -> np.ndarray:
+        """(K,) number of positions at which each original ladder's row
+        differs from the engine's start (one small device reduction)."""
+        return np.asarray(_moved_kernel()(self._node, self._start))[:self.k]
 
     def counts(self) -> np.ndarray:
         """(rows, N, k) resident integer count state (host copy) — the
@@ -569,6 +593,15 @@ class DevicePortfolioRefiner:
                 restarts: List[dict] = []
                 accepted = 0
                 rows = K + slots
+                # a ladder within one swap of its start (at most two
+                # positions differ) runs its next temperature at the first
+                # one.  Where nearly every node sits at J_max (28 of 31 on
+                # the 3-D Fig. 8 instance), nearly every swap raises it: a
+                # cooled ladder there may reject every proposal, or, one
+                # swap away, take the one downhill move, that swap's
+                # reverse, and end at its start.
+                near = np.ones(K, dtype=bool)
+                T_first = max(sched.temperatures[0] * t_scale, 1e-12)
                 cur_keys = np.broadcast_to(np.asarray([jmax0, jsum0]),
                                            (K, 2)).copy()
 
@@ -601,6 +634,7 @@ class DevicePortfolioRefiner:
                     with obs.span("temperature"):
                         T = max(T0 * t_scale, 1e-12)
                         temps = np.full(rows, T)
+                        temps[:K][near] = T_first
                         eps = np.full(rows, eps0)
                         for r in restarts:
                             temps[K + r["slot"]] = max(
@@ -609,6 +643,8 @@ class DevicePortfolioRefiner:
                         rep = eng.run_temperature(temps, sched.sa_moves,
                                                   ctrl.alive, eps)
                         accepted += int(rep.accepted[:K].sum())
+                        if ti + 1 < n_temps:
+                            near = eng.moved_positions() <= 2
                         cur_keys = np.stack([rep.j_max[:K], rep.j_sum[:K]],
                                             axis=1)
                         for r in restarts:

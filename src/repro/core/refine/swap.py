@@ -17,13 +17,15 @@ Two engines implement the same search:
   first-improvement pass applies a maximal set of spatially-disjoint
   improving swaps per batch (positions whose neighbourhood an accepted
   swap touched are masked out, so every applied delta is still exact).
-  Each pass records the spans ``swap.score`` (frontier and gains) and
-  ``swap.apply``, and the counters ``swap.passes``, ``swap.pairs`` (pairs
-  scored) and ``swap.applied`` (see :mod:`repro.obs`).  Given a device
-  scorer (:mod:`repro.core.refine.device_swap`, which only the device
-  portfolio passes), the pairs are scored on the accelerator instead,
-  with the same integer values, and each pass adds its pairs to
-  ``swap.device_pairs`` as well.
+  Each pass records the spans ``swap.score`` (frontier and gains), with
+  ``swap.frontier`` (building the frontier) inside it, and ``swap.apply``,
+  and the counters ``swap.passes``, ``swap.pairs`` (pairs scored) and
+  ``swap.applied`` (see :mod:`repro.obs`).  Given a device scorer
+  (:mod:`repro.core.refine.device_swap`, which only the device portfolio
+  passes), the pairs are scored on the accelerator instead, with the same
+  integer values, and each pass adds its pairs to ``swap.device_pairs``
+  and the pair slots it dispatched (whole chunks) to
+  ``swap.device_slots`` as well.
 * ``engine="scalar"`` — the PR-1 per-vertex Python loop, kept as the
   bit-exact reference the batch engine is tested and benchmarked against.
 
@@ -280,6 +282,8 @@ class SwapRefiner:
         obs.count("swap.pairs", m)
         if self.scorer is not None:
             obs.count("swap.device_pairs", m)
+            c = self.scorer.chunk
+            obs.count("swap.device_slots", -(-m // c) * c)
 
     def _steepest_pass(self, ic: IncrementalCost, swaps: int,
                        budget: float) -> Tuple[bool, int]:
@@ -288,7 +292,8 @@ class SwapRefiner:
             return False, swaps
         obs.count("swap.passes", 1)
         with obs.span("swap.score"):
-            P, Q = self._frontier_pairs(ic)
+            with obs.span("swap.frontier"):
+                P, Q = self._frontier_pairs(ic)
             if P.size:
                 gains, _ = self._batch_gains(ic, P, Q)
                 best = int(np.argmax(gains))
@@ -317,7 +322,8 @@ class SwapRefiner:
         """
         obs.count("swap.passes", 1)
         with obs.span("swap.score"):
-            P, Q = self._frontier_pairs(ic)
+            with obs.span("swap.frontier"):
+                P, Q = self._frontier_pairs(ic)
             if P.size:
                 gains, strict = self._batch_gains(ic, P, Q)
                 improving = gains > self._tol(ic)

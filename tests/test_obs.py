@@ -165,8 +165,12 @@ def test_span_count_is_bounded_by_passes_not_swaps(device_stage):
                      if k.rsplit("/", 1)[-1].startswith("swap."))
     other_calls = sum(c for k, (c, _) in spans.items()
                       if not k.rsplit("/", 1)[-1].startswith("swap."))
-    # one score and at most one apply span per pass
-    assert swap_calls <= 2 * passes
+    # one score with one frontier inside it, and at most one apply span,
+    # per pass
+    assert swap_calls <= 3 * passes
+    for phase in ("rounds", "survivors/polish"):
+        assert spans[f"{phase}/swap.score/swap.frontier"][0] \
+            == spans[f"{phase}/swap.score"][0]
     # rounds, ladders, engine_init, survivors and its four children, and
     # two per temperature: none per row or per move
     assert other_calls == 8 + 2 * TEMPS
