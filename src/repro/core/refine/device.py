@@ -232,6 +232,21 @@ def _temperature_kernel(sa_moves: int):
     return jax.jit(ladder_temperature_scan)
 
 
+def _threefry_keys(seeds: Sequence[int]) -> np.ndarray:
+    """(n, 2) uint32 raw keys, bit for bit ``jax.random.PRNGKey(int(s))``
+    of jax's default threefry implementation for each seed, built on the
+    host with no device call.  ``PRNGKey`` takes the seed as an int64 (a
+    Python int outside int64 raises ``OverflowError`` here too) and keeps
+    its high and low 32-bit words; with x64 off jax narrows the seed to
+    int32 first, so the high word is 0."""
+    import jax
+    s = np.asarray([int(x) for x in seeds], dtype=np.int64).view(np.uint64)
+    hi = s >> np.uint64(32) if jax.config.jax_enable_x64 \
+        else np.zeros_like(s)
+    return np.stack([hi, s & np.uint64(0xFFFFFFFF)],
+                    axis=1).astype(np.uint32)
+
+
 @functools.lru_cache(maxsize=1)
 def _moved_kernel():
     """Jitted ``(rows, p), (p,) -> (rows,)`` count of positions where each
@@ -260,10 +275,8 @@ class DeviceLadderEngine(LadderEngine):
                  seeds: Sequence[int], num_nodes: Optional[int] = None,
                  weighted=False, restart_slots: int = 0,
                  counts_backend="auto"):
-        import jax
         import jax.numpy as jnp
         self._jnp = jnp
-        self._jax = jax
         self.grid, self.stencil = grid, stencil
         table = _memo_table(grid, stencil)
         p = grid.size
@@ -286,13 +299,14 @@ class DeviceLadderEngine(LadderEngine):
             per0 += weights[j] * cn0[0, :, j]
             jsum0 += float(weights[j]) * float(co0[0, j])
         self.start_key = (float(per0.max(initial=0.0)), float(jsum0))
+        # one upload each for the start, its counts and every row's key;
+        # the R copies are made on the device
         self._start = jnp.asarray(A[0], jnp.int32)
-        self._node = jnp.asarray(np.broadcast_to(A, (R, p)), jnp.int32)
-        self._cn = jnp.asarray(
-            np.broadcast_to(cn0, (R, N, stencil.k)), jnp.int32)
-        self._keys = jnp.asarray(np.stack(
-            [np.asarray(jax.random.PRNGKey(int(s)))
-             for s in tuple(seeds) + (0,) * self.slots]))
+        self._node = jnp.broadcast_to(self._start, (R, p))
+        self._cn = jnp.broadcast_to(jnp.asarray(cn0[0], jnp.int32),
+                                    (R, N, stencil.k))
+        self._keys = jnp.asarray(
+            _threefry_keys(tuple(seeds) + (0,) * self.slots))
         self._best_node = self._node
         self._best_jmax = jnp.full(R, self.start_key[0], jnp.float32)
         self._best_jsum = jnp.full(R, self.start_key[1], jnp.float32)
@@ -365,7 +379,7 @@ class DeviceLadderEngine(LadderEngine):
         (the controller's spawn loop then stops without deducting)."""
         if self.n_spawned >= self.slots:
             return None
-        jax, jnp = self._jax, self._jnp
+        jnp = self._jnp
         r = self.k + self.n_spawned
         co, cn = stacked_crossing_counts(
             self.grid, self.stencil, node[None, :], self.n_nodes)
@@ -377,7 +391,7 @@ class DeviceLadderEngine(LadderEngine):
             jnp.asarray(node, jnp.int32))
         self._cn = self._cn.at[r].set(jnp.asarray(cn[0], jnp.int32))
         self._keys = self._keys.at[r].set(
-            jnp.asarray(np.asarray(jax.random.PRNGKey(int(seed)))))
+            jnp.asarray(_threefry_keys((seed,))[0]))
         self._best_node = self._best_node.at[r].set(
             jnp.asarray(node, jnp.int32))
         self._best_jmax = self._best_jmax.at[r].set(jmax)

@@ -42,7 +42,8 @@ from repro.core import (CartGrid, DevicePortfolioRefiner, PlanCache,
                         evaluate, get_mapper, parse_plan,
                         stacked_crossing_counts)
 from repro.core.plan import MappingProblem
-from repro.core.refine.device import DeviceLadderEngine, jax_ready
+from repro.core.refine.device import (DeviceLadderEngine, _threefry_keys,
+                                     jax_ready)
 
 # the refine_suite --tiny instances (same rows as benchmarks.refine_suite)
 TINY = [
@@ -184,6 +185,59 @@ def test_refiner_is_deterministic_end_to_end():
     np.testing.assert_array_equal(r1.assignment, r2.assignment)
     assert (r1.final.j_max, r1.final.j_sum) \
         == (r2.final.j_max, r2.final.j_sum)
+
+
+#: seeds around the int32 and uint32 edges, negative, and past 32 bits
+KEY_SEEDS = (0, 2**31 - 2, 2**31 - 1, 2**31, 2**31 + 1, 2**32 + 3, -1,
+             -12345, 2**40 + 9, -2**40)
+
+
+def _prngkey_loop(seeds):
+    return np.stack([np.asarray(jax.random.PRNGKey(int(s))) for s in seeds])
+
+
+@pytest.mark.parametrize("x64", [False, True], ids=["x32", "x64"])
+def test_threefry_keys_match_prngkey(x64):
+    """The host-built keys are ``PRNGKey``'s, bit for bit, in either x64
+    mode: so every ladder's stream is still that of its own seed."""
+    with jax.enable_x64(x64):
+        got, want = _threefry_keys(KEY_SEEDS), _prngkey_loop(KEY_SEEDS)
+    assert got.dtype == want.dtype and got.shape == want.shape
+    np.testing.assert_array_equal(got, want)
+
+
+def test_engine_and_spawned_keys_match_prngkey():
+    """The engine's rows (seeds, then restart slots keyed by 0) and the
+    key a spawned restart writes are ``PRNGKey`` of their seeds."""
+    grid, start = _instance(21)
+    eng = DeviceLadderEngine(grid, Stencil.nearest_neighbor(2), start,
+                             seeds=(5, 6, 9), num_nodes=5, restart_slots=3)
+    np.testing.assert_array_equal(np.asarray(eng._keys),
+                                  _prngkey_loop((5, 6, 9, 0, 0, 0)))
+    assert eng.spawn_restart(start, seed=2**31 + 7) == 0
+    np.testing.assert_array_equal(np.asarray(eng._keys[3]),
+                                  _prngkey_loop((2**31 + 7,))[0])
+
+
+def test_engine_setup_makes_no_per_row_key_call(monkeypatch):
+    """Set-up builds all 128 rows' keys without one ``PRNGKey`` call, and
+    every row starts as the broadcast start and its counts."""
+    calls = []
+    real = jax.random.PRNGKey
+    monkeypatch.setattr(jax.random, "PRNGKey",
+                        lambda *a, **kw: calls.append(a) or real(*a, **kw))
+    grid, start = _instance(41)
+    st_ = Stencil.nearest_neighbor(2)
+    eng = DeviceLadderEngine(grid, st_, start, seeds=tuple(range(64)),
+                             num_nodes=5, restart_slots=64)
+    assert calls == []
+    _, cn0 = stacked_crossing_counts(grid, st_, start[None], 5,
+                                     use_jax="numpy")
+    for arr, row in ((eng._node, start), (eng._best_node, start),
+                     (eng._cn, cn0[0])):
+        arr = np.asarray(arr)
+        assert arr.dtype == np.int32 and arr.shape == (128,) + row.shape
+        np.testing.assert_array_equal(arr, np.broadcast_to(row, arr.shape))
 
 
 # ---------------------------------------------------------------------------
