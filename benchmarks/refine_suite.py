@@ -814,9 +814,6 @@ def main():
         return
 
     if args.device:
-        from repro.core.refine import jax_ready
-        if not jax_ready():
-            raise SystemExit("--device needs jax (device engine backend)")
         rows = run_device()
         sweep = run_device_sweep()
         print_device_table(rows, sweep)

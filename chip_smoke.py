@@ -209,9 +209,8 @@ def phase_count_state(base_assignment, mesh=SOLVE_MESH, pods=SOLVE_PODS,
     against the numpy recount of the assignments it holds."""
     import numpy as np
     from repro.core import CartGrid, Stencil
-    from repro.core.cost_delta import stacked_count_arrays
+    from repro.core.cost_delta import neighbor_table, stacked_count_arrays
     from repro.core.refine.device import DeviceLadderEngine
-    from repro.core.refine.sharded import _memo_table
     grid, st = CartGrid(mesh), Stencil.nearest_neighbor(len(mesh))
     eng = DeviceLadderEngine(grid, st, base_assignment, seeds=range(k),
                              num_nodes=len(pods), restart_slots=k)
@@ -220,7 +219,7 @@ def phase_count_state(base_assignment, mesh=SOLVE_MESH, pods=SOLVE_PODS,
     rep = eng.run_temperature(np.full(rows, 2.0), sa_moves,
                               np.ones(k, dtype=bool), np.full(rows, eps))
     snap = eng.snapshot()
-    _, cn = stacked_count_arrays(_memo_table(grid, st), snap["nodes"],
+    _, cn = stacked_count_arrays(neighbor_table(grid, st), snap["nodes"],
                                  len(pods))
     check(np.array_equal(cn, snap["counts"]),
           "device count state differs from the numpy recount")

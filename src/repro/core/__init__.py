@@ -2,7 +2,8 @@
 mapping for Cartesian grids (Hunold et al., CS.DC 2020)."""
 from .cost import MappingCost, blocked_assignment, evaluate, node_of_rank_blocked
 from .cost_delta import (BatchSwapDelta, Delta, IncrementalCost,
-                         NeighborTable, PortfolioCost, PortfolioSwapDelta)
+                         NeighborTable, PortfolioCost, PortfolioSwapDelta,
+                         stacked_crossing_counts)
 from .graph import CommGraph, GraphGrid, MaskedGraphGrid, arch_comm_graph
 from .grid import CartGrid, dims_create
 from .mapping import (ANNEALED_PREFIX, DEVICE_PREFIX, HIER_PREFIX, MAPPERS,
@@ -17,7 +18,7 @@ from .refine import (BaseStage, DevicePortfolioRefiner, HierRefiner,
                      RefinedMapper, RefineResult, RefineStage,
                      ScheduledRefiner, ShardedPortfolioRefiner, Stage,
                      StageResult, SwapRefiner, hier_subtree_cache,
-                     refine_assignment, stacked_crossing_counts)
+                     refine_assignment)
 from .plan import (CartResult, MappingPlan, MappingProblem, MappingSolution,
                    PlanCache, cart_create, default_plan_cache, graph_create,
                    parse_plan)

@@ -43,8 +43,10 @@ Usage::
 """
 from __future__ import annotations
 
+import functools
+from collections import OrderedDict
 from dataclasses import dataclass
-from typing import Dict, Iterable, List, Optional, Sequence, Tuple
+from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -54,7 +56,8 @@ from .stencil import Stencil, resolve_weighted
 
 __all__ = ["IncrementalCost", "NeighborTable", "Delta", "BatchSwapDelta",
            "PortfolioCost", "PortfolioSwapDelta", "LOAD_CHUNK_ELEMS",
-           "stacked_count_arrays"]
+           "neighbor_table", "table_key", "stacked_count_arrays",
+           "stacked_crossing_counts"]
 
 
 def stacked_count_arrays(table: "NeighborTable", assignments: np.ndarray,
@@ -62,12 +65,10 @@ def stacked_count_arrays(table: "NeighborTable", assignments: np.ndarray,
     """Integer crossing counts for stacked (K, p) assignments:
     ``((K, k) count_off, (K, N, k) count_node)``.
 
-    THE crossing-count builder — :class:`PortfolioCost` initializes from
-    it, and the sharded engine's numpy fallback
-    (:func:`repro.core.refine.sharded.stacked_crossing_counts`) calls the
-    same function, so the ``counts=`` fast path's "bit-interchangeable
-    producers" contract is upheld mechanically rather than by keeping two
-    copies of this loop in sync.
+    The numpy crossing-count loop: :class:`PortfolioCost` initializes
+    from it (so do the ``multiprocessing`` workers' block states), and it
+    is the reference the jax kernel :func:`stacked_crossing_counts` is
+    tested against, bit for bit.
     """
     A = np.asarray(assignments, dtype=np.int64)
     K, k = A.shape[0], table.out_valid.shape[0]
@@ -127,6 +128,83 @@ class NeighborTable:
         stencil-extracted graphs this returns arrays bit-identical to
         ``build(grid, stencil)`` on the original grid."""
         return NeighborTable.build(graph.grid(), graph.slot_stencil())
+
+
+#: :func:`neighbor_table`'s memo, keyed by :func:`table_key`: the table is
+#: trajectory-independent and grid-sized, so each process builds it once
+#: per problem (the pool workers rebuild block state every temperature).
+_TABLE_MEMO: "OrderedDict[tuple, NeighborTable]" = OrderedDict()
+_TABLE_MEMO_MAX = 8
+
+
+def table_key(grid: CartGrid, stencil: Stencil) -> tuple:
+    """The problem identity a neighbour table depends on.  ``cache_token``
+    keeps graph-backed and masked grids from colliding with a plain
+    :class:`CartGrid` of the same dims (they answer ``shift_ranks``
+    differently, so sharing a table would be silently wrong)."""
+    return (tuple(grid.dims), tuple(grid.periodic),
+            getattr(grid, "cache_token", ""), stencil.offsets)
+
+
+def neighbor_table(grid: CartGrid, stencil: Stencil) -> NeighborTable:
+    """:meth:`NeighborTable.build`, memoized per process by
+    :func:`table_key` (the last few problems are kept)."""
+    key = table_key(grid, stencil)
+    table = _TABLE_MEMO.get(key)
+    if table is None:
+        table = NeighborTable.build(grid, stencil)
+        _TABLE_MEMO[key] = table
+        while len(_TABLE_MEMO) > _TABLE_MEMO_MAX:
+            _TABLE_MEMO.popitem(last=False)
+    else:
+        _TABLE_MEMO.move_to_end(key)
+    return table
+
+
+def stacked_crossing_counts(grid: CartGrid, stencil: Stencil,
+                            assignments: np.ndarray, num_nodes: int) \
+        -> Tuple[np.ndarray, np.ndarray]:
+    """Integer crossing counts for a stacked (K, p) assignment array:
+    ``((K, k) count_off, (K, N, k) count_node)``, bit-equal to
+    :func:`stacked_count_arrays` and so to what :class:`PortfolioCost`
+    builds in its own init (integers: exact).  One ``jax.vmap``-batched
+    kernel over the rows (crossing masks and a ``segment_sum`` per
+    offset), jitted once per shape.  This is the count state the device
+    engine (:mod:`repro.core.refine.device`) seeds its ladders from and
+    rekeys its survivors with, and that the sharded engine's serial
+    backend hands each block.  jax is imported on the first call."""
+    import jax.numpy as jnp
+    table = neighbor_table(grid, stencil)
+    co, cn = _jit_stacked_counts(int(num_nodes))(
+        jnp.asarray(np.asarray(assignments, dtype=np.int64)),
+        jnp.asarray(table.out_valid), jnp.asarray(table.out_tgt))
+    return (np.asarray(co, dtype=np.int64),
+            np.ascontiguousarray(np.asarray(cn, dtype=np.int64)
+                                 .transpose(0, 2, 1)))
+
+
+@functools.lru_cache(maxsize=8)
+def _jit_stacked_counts(num_nodes: int):
+    """Build (and cache) the jitted stacked-counts kernel for one node
+    count.  ``num_segments`` must be static under jit; table arrays are
+    traced arguments, so one cached callable serves every grid/stencil —
+    jax's own jit cache keys the shapes.  The jitted function keeps the
+    stable name ``stacked_crossing_counts_one`` (module
+    ``jit_stacked_crossing_counts_one``) for trace reductions."""
+    import jax
+    import jax.numpy as jnp
+
+    def stacked_crossing_counts_one(a, out_valid, out_tgt):     # a: (p,)
+        crossing = out_valid & (a[None, :] != a[out_tgt])        # (k, p)
+        count_off = crossing.sum(axis=1)
+        # count_node[j, n] = #{i : crossing[j, i] and a[i] == n}
+        count_node = jax.vmap(
+            lambda c: jax.ops.segment_sum(c.astype(jnp.int32), a,
+                                          num_segments=num_nodes))(crossing)
+        return count_off, count_node                 # (k,), (k, N)
+
+    return jax.jit(jax.vmap(stacked_crossing_counts_one,
+                            in_axes=(0, None, None)))
 
 
 @dataclass(frozen=True)
@@ -536,12 +614,10 @@ class PortfolioCost:
         self.node = assignments.copy()
         k = stencil.k
         if counts is not None:
-            # precomputed integer crossing counts (e.g. the sharded
-            # engine's jax.vmap kernel — see
-            # :func:`repro.core.refine.sharded.stacked_crossing_counts`).
-            # Counts are pure integers, so any correct producer is
-            # bit-interchangeable with the loop below; shapes are checked,
-            # values trusted.
+            # precomputed integer crossing counts (the jax kernel,
+            # :func:`stacked_crossing_counts`).  Counts are pure integers,
+            # so any correct producer is bit-interchangeable with the loop
+            # below; shapes are checked, values trusted.
             count_off, count_node = counts
             self._count_off = np.array(count_off, dtype=np.int64)
             self._count_node = np.array(count_node, dtype=np.int64)

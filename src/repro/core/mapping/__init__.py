@@ -42,7 +42,7 @@ Names resolve in two layers:
                 accelerator (vmapped Metropolis moves over stacked
                 crossing-count state, one ``lax.scan`` per
                 temperature); same boundary protocol, scales to
-                K=1024; delegates to ``portfolio:`` without jax
+                K=1024
    hier:        :class:`~repro.core.refine.HierRefiner` — recursive   (J_max, J_sum)
                 multilevel mapping down a topology tree: group the
                 nodes by per-level fan-outs

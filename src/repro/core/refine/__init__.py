@@ -7,10 +7,10 @@ Hierarchical Process Mapping") shows that cheap local search on top of a
 good initial mapping recovers most of the remaining J_sum/J_max gap.  This
 package supplies that pass in three tiers:
 
-* :class:`SwapRefiner` — boundary swap local search scored by the batched
-  numpy engine (:meth:`~repro.core.cost_delta.IncrementalCost.batch_swap_deltas`):
+* :class:`SwapRefiner` — boundary swap local search scored in batches
+  (:meth:`~repro.core.cost_delta.IncrementalCost.batch_swap_deltas`):
   the whole candidate frontier is evaluated per sweep in a handful of
-  vectorized passes (``engine="scalar"`` keeps the PR-1 reference loop).
+  vectorized passes.
 * :class:`ScheduledRefiner` — alternates j_sum/j_max SwapRefiner phases
   (optionally with a simulated-annealing temperature ladder) so bottleneck
   relief doesn't stall at the first J_max plateau.
@@ -47,8 +47,8 @@ from .schedule import ScheduledRefiner
 from .engine import (BoundaryController, BoundaryReport, LadderEngine,
                      RestartSeeder, SerialLadderEngine)
 from .portfolio import PortfolioRefiner, run_temperature
-from .sharded import ShardedPortfolioRefiner, stacked_crossing_counts
-from .device import DeviceLadderEngine, DevicePortfolioRefiner, jax_ready
+from .sharded import ShardedPortfolioRefiner
+from .device import DeviceLadderEngine, DevicePortfolioRefiner
 from .hier import HierRefiner, MaskedGrid, hier_subtree_cache
 from .stage import BaseStage, RefineStage, Stage, StageResult
 from .mapper import RefinedMapper
@@ -56,9 +56,8 @@ from .mapper import RefinedMapper
 __all__ = ["SwapRefiner", "ScheduledRefiner", "PortfolioRefiner",
            "ShardedPortfolioRefiner", "DevicePortfolioRefiner",
            "HierRefiner", "MaskedGrid", "hier_subtree_cache",
-           "run_temperature", "stacked_crossing_counts",
+           "run_temperature",
            "LadderEngine", "SerialLadderEngine", "DeviceLadderEngine",
            "BoundaryController", "BoundaryReport", "RestartSeeder",
-           "jax_ready",
            "RefineResult", "refine_assignment", "RefinedMapper",
            "Stage", "StageResult", "BaseStage", "RefineStage"]

@@ -7,9 +7,8 @@ This engine moves the *whole temperature* onto the accelerator:
 
 * the integer crossing-count state for K stacked assignments — the
   ``(K, N, k)`` ``count_node`` arrays of
-  :func:`~repro.core.refine.sharded.stacked_crossing_counts`, promoted here
-  from an opt-in counts producer to the resident state representation —
-  lives on the device for the entire run;
+  :func:`~repro.core.cost_delta.stacked_crossing_counts` — lives on the
+  device for the entire run;
 * proposals are drawn with ``jax.random`` (one key per ladder, split per
   move, so a ladder's stream depends only on its own seed — deterministic
   and batch-composition-independent);
@@ -52,10 +51,10 @@ ride in the stacked state from the start (inactive until spawned), so a
 spawn at a temperature boundary is a row write, never a shape change — the
 jitted temperature kernel compiles once per (K + slots, p, N, k) shape.
 
-Without jax (or for ``max_swaps`` budgets and ``pinned`` repair masks,
-whose move-level coupling is host semantics), the refiner delegates to the
-single-process :class:`~repro.core.refine.portfolio.PortfolioRefiner` —
-same seeds, same schedule — so every spelling works in every environment.
+Runs with a ``max_swaps`` budget or a ``pinned`` repair mask, whose
+move-level coupling is host semantics, delegate to the single-process
+:class:`~repro.core.refine.portfolio.PortfolioRefiner` with the same seeds
+and schedule.
 
 Usage::
 
@@ -70,38 +69,21 @@ import copy
 import functools
 import math
 import time
-import warnings
 from typing import List, Optional, Sequence, Tuple
 
 import numpy as np
 
 from ... import obs
-from ..cost_delta import IncrementalCost, PortfolioCost
+from ..cost_delta import (IncrementalCost, PortfolioCost, neighbor_table,
+                          stacked_crossing_counts)
 from ..grid import CartGrid
 from ..stencil import Stencil, resolve_weighted
 from .engine import (BoundaryController, BoundaryReport, LadderEngine,
                      RestartSeeder, phase_stats)
 from .portfolio import PortfolioRefiner
-from .sharded import _memo_table, stacked_crossing_counts
 from .swap import RefineResult
 
-__all__ = ["DeviceLadderEngine", "DevicePortfolioRefiner", "jax_ready"]
-
-#: memoized "does jax import and initialize?" verdict (None = undecided).
-_JAX_READY: Optional[bool] = None
-
-
-def jax_ready() -> bool:
-    """True when jax actually imports (the device engine runs real jitted
-    kernels, so spec discovery is not enough).  Cached per process."""
-    global _JAX_READY
-    if _JAX_READY is None:
-        try:
-            import jax  # noqa: F401
-            _JAX_READY = True
-        except Exception:           # pragma: no cover - no jax in image
-            _JAX_READY = False
-    return _JAX_READY
+__all__ = ["DeviceLadderEngine", "DevicePortfolioRefiner"]
 
 
 @functools.lru_cache(maxsize=16)
@@ -273,12 +255,11 @@ class DeviceLadderEngine(LadderEngine):
 
     def __init__(self, grid: CartGrid, stencil: Stencil, start: np.ndarray,
                  seeds: Sequence[int], num_nodes: Optional[int] = None,
-                 weighted=False, restart_slots: int = 0,
-                 counts_backend="auto"):
+                 weighted=False, restart_slots: int = 0):
         import jax.numpy as jnp
         self._jnp = jnp
         self.grid, self.stencil = grid, stencil
-        table = _memo_table(grid, stencil)
+        table = neighbor_table(grid, stencil)
         p = grid.size
         self.k = K = len(seeds)
         self.slots = int(restart_slots)
@@ -291,8 +272,7 @@ class DeviceLadderEngine(LadderEngine):
         # the resident state representation: stacked integer crossing
         # counts (one row per ladder, broadcast from the shared start)
         A = np.broadcast_to(np.asarray(start, dtype=np.int64), (1, p))
-        co0, cn0 = stacked_crossing_counts(grid, stencil, A, N,
-                                           use_jax=counts_backend)
+        co0, cn0 = stacked_crossing_counts(grid, stencil, A, N)
         per0 = np.zeros(N, dtype=np.float64)
         jsum0 = 0.0
         for j in range(stencil.k):      # host-exact start key
@@ -428,9 +408,6 @@ class DevicePortfolioRefiner:
       restart_slots: preallocated restart rows (static shapes — the
         temperature kernel compiles once).  ``"auto"`` sizes the pool at K
         when ``restarts`` is enabled, 0 otherwise.
-      counts_backend: backend for the crossing-count state seeding and the
-        end-of-run exact rekeying (``"auto"``/``"jax"``/``"numpy"`` — see
-        :func:`~repro.core.refine.sharded.stacked_crossing_counts`).
       engine_factory: replace :class:`DeviceLadderEngine` (testing seam).
         A factory is an opaque object, so hand-built instances carrying
         one have no stable spelling and their plans are **uncacheable**
@@ -438,9 +415,8 @@ class DevicePortfolioRefiner:
         ``tests/test_plan.py``).
 
     ``max_swaps`` budgets and ``pinned`` masks couple ladders at move
-    granularity on the host; such runs (and jax-less environments)
-    delegate to the single-process portfolio with the same seeds and
-    schedule, so every spelling works everywhere.
+    granularity on the host; such runs delegate to the single-process
+    portfolio with the same seeds and schedule.
     """
 
     def __init__(self, k: int = 8, seed: int = 0,
@@ -450,11 +426,10 @@ class DevicePortfolioRefiner:
                  restarts=None, retune: bool = False,
                  accept_band: Tuple[float, float] = (0.05, 0.5),
                  retune_bounds: Tuple[float, float] = (0.25, 4.0),
-                 restart_slots="auto", counts_backend="auto",
+                 restart_slots="auto",
                  objectives: Sequence[str] = ("j_sum", "j_max"),
                  rounds: int = 4, policy: str = "first", max_passes: int = 8,
-                 weighted="auto", tol: float = 1e-12,
-                 max_partners: int = 32, engine: str = "batch",
+                 weighted="auto", tol: float = 1e-12, max_partners: int = 32,
                  temperatures: Sequence[float] = (2.0, 1.0, 0.5, 0.25),
                  sa_moves: int = 200, max_swaps: Optional[int] = None,
                  engine_factory=None):
@@ -469,15 +444,12 @@ class DevicePortfolioRefiner:
                              "(0 < min <= 1 <= max)")
         if restart_slots != "auto" and int(restart_slots) < 0:
             raise ValueError('restart_slots must be "auto" or an int >= 0')
-        if counts_backend not in (True, False, "auto", "jax", "numpy"):
-            raise ValueError('counts_backend must be True, False, "auto", '
-                             '"jax", or "numpy"')
         self.portfolio = PortfolioRefiner(
             k=k, seed=seed, seeds=seeds, kill_factor=kill_factor,
             polish_top=polish_top, objectives=objectives, rounds=rounds,
             policy=policy, max_passes=max_passes, weighted=weighted, tol=tol,
-            max_partners=max_partners, engine=engine,
-            temperatures=temperatures, sa_moves=sa_moves, max_swaps=None)
+            max_partners=max_partners, temperatures=temperatures,
+            sa_moves=sa_moves, max_swaps=None)
         self.schedule = self.portfolio.schedule
         self.seeds = self.portfolio.seeds
         self.k = self.portfolio.k
@@ -489,7 +461,6 @@ class DevicePortfolioRefiner:
         self.retune_bounds = (blo, bhi)
         self.restart_slots = restart_slots if restart_slots == "auto" \
             else int(restart_slots)
-        self.counts_backend = counts_backend
         if max_swaps is not None and int(max_swaps) < 0:
             raise ValueError("max_swaps must be >= 0 (or None)")
         self.max_swaps = None if max_swaps is None else int(max_swaps)
@@ -511,7 +482,6 @@ class DevicePortfolioRefiner:
                     "accept_band": self.accept_band,
                     "retune_bounds": self.retune_bounds,
                     "restart_slots": self.restart_slots,
-                    "counts_backend": self.counts_backend,
                     "max_swaps": self.max_swaps,
                     "engine_factory": self.engine_factory})
         return cfg
@@ -543,12 +513,6 @@ class DevicePortfolioRefiner:
                                   num_nodes, pinned)
         if pinned is not None:
             return self._delegate("pinned", grid, stencil, node_of_pos,
-                                  num_nodes, pinned)
-        if not jax_ready():         # pragma: no cover - jax in test image
-            warnings.warn("jax unavailable: device portfolio delegating to "
-                          "the single-process host engine", UserWarning,
-                          stacklevel=2)
-            return self._delegate("no-jax", grid, stencil, node_of_pos,
                                   num_nodes, pinned)
         t0 = time.perf_counter()
         sched = self.schedule
@@ -591,8 +555,7 @@ class DevicePortfolioRefiner:
                 with obs.span("engine_init"):
                     eng = factory(grid, stencil, cur, self.seeds,
                                   num_nodes=n_nodes, weighted=sched.weighted,
-                                  restart_slots=slots,
-                                  counts_backend=self.counts_backend)
+                                  restart_slots=slots)
                 jmax0, jsum0 = eng.start_key
                 eps0 = float(1.0 / (1.0 + abs(jsum0)))
                 n_temps = len(sched.temperatures)
@@ -691,13 +654,12 @@ class DevicePortfolioRefiner:
                     pick = alive_rows + slot_rows
                     cand = np.concatenate([snap["nodes"][pick],
                                            snap["best_nodes"][pick]])
-                    counts = stacked_crossing_counts(
-                        grid, stencil, cand, n_nodes,
-                        use_jax=self.counts_backend)
+                    counts = stacked_crossing_counts(grid, stencil, cand,
+                                                     n_nodes)
                     cpc = PortfolioCost(grid, stencil, cand,
                                         num_nodes=n_nodes,
                                         weighted=sched.weighted,
-                                        table=_memo_table(grid, stencil),
+                                        table=neighbor_table(grid, stencil),
                                         counts=counts)
                 with obs.span("polish"):
                     swaps, passes, polish_order = \
@@ -715,7 +677,6 @@ class DevicePortfolioRefiner:
             "k": self.k,
             "seeds": self.seeds,
             "backend": f"device[{_backend_name()}]",
-            "counts_backend": self.counts_backend,
             "boundaries": eng.boundaries,
             "proposals": rows * n_temps * sched.sa_moves,
             "sa_accepted": accepted,
@@ -735,8 +696,5 @@ class DevicePortfolioRefiner:
 
 
 def _backend_name() -> str:
-    try:
-        import jax
-        return jax.default_backend()
-    except Exception:               # pragma: no cover - jax in test image
-        return "none"
+    import jax
+    return jax.default_backend()

@@ -43,10 +43,9 @@ from typing import Optional, Tuple
 
 import numpy as np
 
-from ..cost_delta import IncrementalCost
+from ..cost_delta import IncrementalCost, neighbor_table, table_key
 from ..grid import CartGrid
 from ..stencil import Stencil
-from .sharded import _memo_table, _table_key
 
 __all__ = ["CHUNK", "DeviceSwapScorer", "device_swap_scorer"]
 
@@ -156,10 +155,10 @@ def _scores_kernel():
 def _device_table(grid: CartGrid, stencil: Stencil) -> tuple:
     """The neighbour table on the device, uploaded once per problem."""
     import jax
-    key = _table_key(grid, stencil)
+    key = table_key(grid, stencil)
     dev = _DEVICE_TABLES.get(key)
     if dev is None:
-        t = _memo_table(grid, stencil)
+        t = neighbor_table(grid, stencil)
         dev = tuple(jax.device_put(a) for a in (
             t.out_valid, t.out_tgt.astype(np.int32),
             t.in_valid, t.in_src.astype(np.int32)))

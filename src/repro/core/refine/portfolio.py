@@ -163,7 +163,7 @@ class PortfolioRefiner:
       identical names and defaults as :class:`ScheduledRefiner`
       (``objectives``, ``rounds``, ``policy``, ``max_passes``, ``weighted``
       — ``"auto"`` keys byte-weighted scoring off the stencil — ``tol``,
-      ``max_partners``, ``engine``, ``temperatures``, ``sa_moves``).
+      ``max_partners``, ``temperatures``, ``sa_moves``).
     """
 
     def __init__(self, k: int = 8, seed: int = 0,
@@ -172,8 +172,7 @@ class PortfolioRefiner:
                  polish_top: Optional[int] = 3,
                  objectives: Sequence[str] = ("j_sum", "j_max"),
                  rounds: int = 4, policy: str = "first", max_passes: int = 8,
-                 weighted="auto", tol: float = 1e-12,
-                 max_partners: int = 32, engine: str = "batch",
+                 weighted="auto", tol: float = 1e-12, max_partners: int = 32,
                  temperatures: Sequence[float] = (2.0, 1.0, 0.5, 0.25),
                  sa_moves: int = 200, max_swaps: Optional[int] = None):
         if seeds is not None:
@@ -209,7 +208,7 @@ class PortfolioRefiner:
         self.schedule = ScheduledRefiner(
             objectives=objectives, rounds=rounds, policy=policy,
             max_passes=max_passes, weighted=weighted, tol=tol,
-            max_partners=max_partners, engine=engine, anneal=True,
+            max_partners=max_partners, anneal=True,
             temperatures=temperatures, sa_moves=sa_moves, seed=seeds[0])
 
     def as_stage(self, budget: Optional[int] = None):

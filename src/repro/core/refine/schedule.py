@@ -50,7 +50,7 @@ class ScheduledRefiner:
         ``refined:<base>`` pass exactly — then relieves the bottleneck.
       rounds: maximum schedule rounds; a round with zero accepted swaps
         stops early.
-      policy / max_passes / weighted / tol / max_partners / engine:
+      policy / max_passes / weighted / tol / max_partners:
         forwarded to each phase's :class:`SwapRefiner`.
       anneal: append the SA ladder after the deterministic schedule.
       temperatures: SA ladder (descending), in units of one unit-weight
@@ -66,8 +66,7 @@ class ScheduledRefiner:
     def __init__(self, objectives: Sequence[str] = ("j_sum", "j_max"),
                  rounds: int = 4, policy: str = "first", max_passes: int = 8,
                  weighted="auto", tol: float = 1e-12,
-                 max_partners: int = 32, engine: str = "batch",
-                 anneal: bool = False,
+                 max_partners: int = 32, anneal: bool = False,
                  temperatures: Sequence[float] = (2.0, 1.0, 0.5, 0.25),
                  sa_moves: int = 200, seed: int = 0,
                  max_swaps: Optional[int] = None):
@@ -78,8 +77,7 @@ class ScheduledRefiner:
                              "deterministic rounds, ladder/polish only)")
         # validate eagerly (same errors as SwapRefiner would raise later)
         for obj in objectives:
-            SwapRefiner(objective=obj, policy=policy, max_passes=max_passes,
-                        engine=engine)
+            SwapRefiner(objective=obj, policy=policy, max_passes=max_passes)
         self.objectives = tuple(objectives)
         self.rounds = int(rounds)
         self.policy = policy
@@ -87,7 +85,6 @@ class ScheduledRefiner:
         self.weighted = weighted
         self.tol = float(tol)
         self.max_partners = int(max_partners)
-        self.engine = engine
         self.anneal = bool(anneal)
         self.temperatures = tuple(float(t) for t in temperatures)
         self.sa_moves = int(sa_moves)
@@ -109,8 +106,8 @@ class ScheduledRefiner:
         return {"objectives": self.objectives, "rounds": self.rounds,
                 "policy": self.policy, "max_passes": self.max_passes,
                 "weighted": self.weighted, "tol": self.tol,
-                "max_partners": self.max_partners, "engine": self.engine,
-                "anneal": self.anneal, "temperatures": self.temperatures,
+                "max_partners": self.max_partners, "anneal": self.anneal,
+                "temperatures": self.temperatures,
                 "sa_moves": self.sa_moves, "seed": self.seed,
                 "max_swaps": self.max_swaps}
 
@@ -120,8 +117,7 @@ class ScheduledRefiner:
         return SwapRefiner(objective=objective, policy=self.policy,
                            max_passes=self.max_passes, weighted=self.weighted,
                            tol=self.tol, max_partners=self.max_partners,
-                           engine=self.engine, max_swaps=max_swaps,
-                           scorer=scorer)
+                           max_swaps=max_swaps, scorer=scorer)
 
     def _sa_ladder(self, grid: CartGrid, stencil: Stencil,
                    assignment: np.ndarray, num_nodes: Optional[int],

@@ -43,12 +43,12 @@ engine's candidate set is a strict superset.  That is the structural
 guarantee behind "adaptive on is lexicographically never worse on the
 (J_max, J_sum) key" (also pinned by tests).
 
-The optional jax path (:func:`stacked_crossing_counts`,
-``vmap_counts=True``) computes each block's integer crossing-count state
-with one ``jax.vmap``-batched kernel over the stacked assignment arrays
-instead of the per-offset numpy loop.  Counts are pure integers, so both
-producers are bit-interchangeable; without jax the numpy path is used
-silently.
+The serial backend computes each block's integer crossing-count state
+with the jax kernel
+(:func:`~repro.core.cost_delta.stacked_crossing_counts`) and hands it to
+the block; the ``multiprocessing`` workers stay numpy-only and build it
+with :func:`~repro.core.cost_delta.stacked_count_arrays`.  Counts are
+pure integers, so both producers are bit-interchangeable.
 
 Usage::
 
@@ -61,30 +61,26 @@ Usage::
 from __future__ import annotations
 
 import copy
-import functools
 import math
 import multiprocessing
 import os
 import pickle
-import sys
 import time
-from collections import OrderedDict
 from contextlib import contextmanager
 from typing import List, Optional, Sequence, Tuple
 
 import numpy as np
 
 from ... import obs
-from ..cost_delta import (IncrementalCost, NeighborTable, PortfolioCost,
-                          stacked_count_arrays)
+from ..cost_delta import (IncrementalCost, PortfolioCost, neighbor_table,
+                          stacked_crossing_counts)
 from ..grid import CartGrid
 from ..stencil import Stencil, resolve_weighted
 from .engine import BoundaryController, RestartSeeder, phase_stats
 from .portfolio import PortfolioRefiner, run_temperature
 from .swap import RefineResult
 
-__all__ = ["ShardedPortfolioRefiner", "stacked_crossing_counts",
-           "IpcMeter", "measure_ipc"]
+__all__ = ["ShardedPortfolioRefiner", "IpcMeter", "measure_ipc"]
 
 #: auto backend: fork+pickle round-trips per temperature only pay off once
 #: the per-temperature batched numpy work dominates the IPC (measured
@@ -144,111 +140,6 @@ def measure_ipc():
         _IPC_METER = prev
 
 
-#: memoized "is jax importable" verdict (``None`` = undecided).  Resolved
-#: once per process from spec discovery, NOT from ``sys.modules`` — the
-#: PR-5 ``"jax" in sys.modules`` probe made the very first
-#: ``use_jax="auto"`` call depend on whether anything else had imported
-#: jax yet (import-order-dependent first-call behavior, pinned by a
-#: regression test).  Spec discovery doesn't pay the import; the first
-#: call that actually selects the jax backend does.
-_JAX_SPEC: Optional[bool] = None
-
-
-def _jax_importable() -> bool:
-    global _JAX_SPEC
-    if _JAX_SPEC is None:
-        import importlib.util
-        _JAX_SPEC = importlib.util.find_spec("jax") is not None
-    return _JAX_SPEC
-
-
-def _jax_available() -> bool:
-    """Deprecated PR-5 probe, kept for backward compatibility; backend
-    resolution now goes through :func:`_jax_importable` so it never
-    depends on import order."""
-    return "jax" in sys.modules
-
-
-def _resolve_counts_backend(use_jax) -> bool:
-    """Map a counts-backend option to "use the jax kernel?".  Accepts the
-    historical ``True`` / ``False`` / ``"auto"`` plus the explicit
-    spellings ``"jax"`` / ``"numpy"`` (threadable through ``config()`` and
-    bracket options)."""
-    if use_jax == "auto":
-        return _jax_importable()
-    if use_jax == "numpy":
-        return False
-    if use_jax == "jax":
-        return True
-    return bool(use_jax)
-
-
-def stacked_crossing_counts(grid: CartGrid, stencil: Stencil,
-                            assignments: np.ndarray, num_nodes: int,
-                            use_jax="auto") \
-        -> Tuple[np.ndarray, np.ndarray]:
-    """Integer crossing counts for a stacked (K, p) assignment array:
-    ``((K, k) count_off, (K, N, k) count_node)``, bit-equal to what
-    :class:`~repro.core.cost_delta.PortfolioCost` builds in its own init
-    loop (integers — exact on every path).  This is the state
-    representation the device-resident engine
-    (:mod:`repro.core.refine.device`) seeds its ladders from and the
-    numpy fallback every backend shares.
-
-    ``use_jax`` selects the backend: ``"jax"``/``True`` runs one
-    ``jax.vmap``-batched kernel over the stacked assignments (crossing
-    masks + ``segment_sum`` per offset, jitted once per shape),
-    ``"numpy"``/``False`` the stacked numpy loop, and ``"auto"`` the jax
-    kernel exactly when jax is *importable* — a property of the
-    environment, never of import order.  Falls back to numpy when jax is
-    selected but missing.
-    """
-    A = np.asarray(assignments, dtype=np.int64)
-    table = _memo_table(grid, stencil)
-    N = int(num_nodes)
-    if _resolve_counts_backend(use_jax):
-        try:
-            return _jax_stacked_counts(table, A, N)
-        except ImportError:
-            pass
-    return stacked_count_arrays(table, A, N)
-
-
-@functools.lru_cache(maxsize=8)
-def _jit_stacked_counts(num_nodes: int):
-    """Build (and cache) the jitted stacked-counts kernel for one node
-    count.  ``num_segments`` must be static under jit; table arrays are
-    traced arguments, so one cached callable serves every grid/stencil —
-    jax's own jit cache keys the shapes.  The jitted function keeps the
-    stable name ``stacked_crossing_counts_one`` (module
-    ``jit_stacked_crossing_counts_one``) for trace reductions."""
-    import jax
-    import jax.numpy as jnp
-
-    def stacked_crossing_counts_one(a, out_valid, out_tgt):     # a: (p,)
-        crossing = out_valid & (a[None, :] != a[out_tgt])        # (k, p)
-        count_off = crossing.sum(axis=1)
-        # count_node[j, n] = #{i : crossing[j, i] and a[i] == n}
-        count_node = jax.vmap(
-            lambda c: jax.ops.segment_sum(c.astype(jnp.int32), a,
-                                          num_segments=num_nodes))(crossing)
-        return count_off, count_node                 # (k,), (k, N)
-
-    return jax.jit(jax.vmap(stacked_crossing_counts_one,
-                            in_axes=(0, None, None)))
-
-
-def _jax_stacked_counts(table: NeighborTable, A: np.ndarray,
-                        N: int) -> Tuple[np.ndarray, np.ndarray]:
-    import jax.numpy as jnp
-    co, cn = _jit_stacked_counts(N)(jnp.asarray(A),
-                                    jnp.asarray(table.out_valid),
-                                    jnp.asarray(table.out_tgt))
-    return (np.asarray(co, dtype=np.int64),
-            np.ascontiguousarray(np.asarray(cn, dtype=np.int64)
-                                 .transpose(0, 2, 1)))
-
-
 def worker_context():
     """The ``multiprocessing`` context of the numpy-only worker pools:
     fork (spawn where there is none).  Fork keeps the workers cheap: no
@@ -266,42 +157,15 @@ def worker_context():
 # the per-(block, temperature) worker task
 
 
-#: NeighborTable memo keyed by (dims, periodic, offsets): persistent pool
-#: workers rebuild block state every temperature, but the table is
-#: trajectory-independent and grid-sized — build it once per process.
-_TABLE_MEMO: "OrderedDict[tuple, NeighborTable]" = OrderedDict()
-_TABLE_MEMO_MAX = 8
-
-
-def _table_key(grid: CartGrid, stencil: Stencil) -> tuple:
-    # cache_token keeps graph-backed and masked grids from colliding with
-    # a plain CartGrid of the same dims (they answer shift_ranks
-    # differently, so sharing a table would be silently wrong).
-    return (tuple(grid.dims), tuple(grid.periodic),
-            getattr(grid, "cache_token", ""), stencil.offsets)
-
-
-def _memo_table(grid: CartGrid, stencil: Stencil) -> NeighborTable:
-    key = _table_key(grid, stencil)
-    table = _TABLE_MEMO.get(key)
-    if table is None:
-        table = NeighborTable.build(grid, stencil)
-        _TABLE_MEMO[key] = table
-        while len(_TABLE_MEMO) > _TABLE_MEMO_MAX:
-            _TABLE_MEMO.popitem(last=False)
-    else:
-        _TABLE_MEMO.move_to_end(key)
-    return table
-
-
 def _block_step(payload: dict) -> dict:
     """Advance one seed block through one temperature of proposals.
 
     Module-level and primitives-only (dims/offsets/arrays/rng generators —
     all picklable) so it ships to ``multiprocessing`` workers; the serial
     backend calls it inline.  The block's cost state is rebuilt from its
-    assignment rows each call (integer counts — exact), optionally via the
-    jax.vmap kernel when the coordinator precomputed ``counts``.
+    assignment rows each call (integer counts — exact), from the
+    ``counts`` the serial coordinator precomputed with the jax kernel when
+    the payload carries them.
     """
     grid = payload.get("grid")
     if grid is None:
@@ -310,7 +174,7 @@ def _block_step(payload: dict) -> dict:
     pc = PortfolioCost(grid, stencil, payload["node"],
                        num_nodes=payload["num_nodes"],
                        weighted=payload["weighted"],
-                       table=_memo_table(grid, stencil),
+                       table=neighbor_table(grid, stencil),
                        counts=payload.get("counts"))
     rngs = payload["rngs"]
     done = np.array(payload["done"], dtype=bool)
@@ -348,13 +212,6 @@ class ShardedPortfolioRefiner:
         picks ``"mp"`` when ``shards > 1`` and the stacked state is large
         enough to amortize IPC.
       workers: process-pool size cap (default: min(shards, cpu count)).
-      vmap_counts: counts backend for rebuilding block cost state —
-        ``"jax"``/``True`` the jax.vmap kernel, ``"numpy"``/``False`` the
-        stacked numpy loop, ``"auto"`` jax exactly when it is importable
-        (an environment property; never depends on import order — results
-        are bit-identical either way).  Serial backend only: mp workers
-        are numpy-only by design (no jax in worker processes), so the flag
-        is inert there.
       Remaining arguments are :class:`PortfolioRefiner`'s, same defaults —
       a bare ``sharded:<base>`` equals a bare ``portfolio:<base>``.
     """
@@ -365,13 +222,11 @@ class ShardedPortfolioRefiner:
                  accept_band: Tuple[float, float] = (0.05, 0.5),
                  retune_bounds: Tuple[float, float] = (0.25, 4.0),
                  backend: str = "auto", workers: Optional[int] = None,
-                 vmap_counts="auto",
                  kill_factor: Optional[float] = 1.5,
                  polish_top: Optional[int] = 3,
                  objectives: Sequence[str] = ("j_sum", "j_max"),
                  rounds: int = 4, policy: str = "first", max_passes: int = 8,
-                 weighted="auto", tol: float = 1e-12,
-                 max_partners: int = 32, engine: str = "batch",
+                 weighted="auto", tol: float = 1e-12, max_partners: int = 32,
                  temperatures: Sequence[float] = (2.0, 1.0, 0.5, 0.25),
                  sa_moves: int = 200, max_swaps: Optional[int] = None):
         if int(shards) < 1:
@@ -380,9 +235,6 @@ class ShardedPortfolioRefiner:
             raise ValueError('restarts must be None, "auto", or an int >= 0')
         if backend not in ("auto", "serial", "mp"):
             raise ValueError('backend must be "auto", "serial", or "mp"')
-        if vmap_counts not in (True, False, "auto", "jax", "numpy"):
-            raise ValueError('vmap_counts must be True, False, "auto", '
-                             '"jax", or "numpy"')
         lo, hi = float(accept_band[0]), float(accept_band[1])
         if not (0.0 <= lo <= hi <= 1.0):
             raise ValueError("accept_band must satisfy 0 <= low <= high <= 1")
@@ -398,15 +250,14 @@ class ShardedPortfolioRefiner:
         self.retune_bounds = (blo, bhi)
         self.backend = backend
         self.workers = None if workers is None else int(workers)
-        self.vmap_counts = vmap_counts
         # the single-process engine this one must replicate: seeds,
         # schedule, kill/polish rules, and the budget-delegation target.
         self.portfolio = PortfolioRefiner(
             k=k, seed=seed, seeds=seeds, kill_factor=kill_factor,
             polish_top=polish_top, objectives=objectives, rounds=rounds,
             policy=policy, max_passes=max_passes, weighted=weighted, tol=tol,
-            max_partners=max_partners, engine=engine,
-            temperatures=temperatures, sa_moves=sa_moves, max_swaps=None)
+            max_partners=max_partners, temperatures=temperatures,
+            sa_moves=sa_moves, max_swaps=None)
         self.schedule = self.portfolio.schedule
         self.seeds = self.portfolio.seeds
         self.k = self.portfolio.k
@@ -430,14 +281,13 @@ class ShardedPortfolioRefiner:
     def config(self) -> dict:
         """Full constructor configuration — the stage layer's canonical
         cache identity for hand-built refiners.  Execution-only knobs
-        (backend/workers/vmap_counts) are included for faithfulness even
-        though every backend returns bit-identical results."""
+        (backend/workers) are included for faithfulness even though every
+        backend returns bit-identical results."""
         cfg = self.portfolio.config()
         cfg.update({"shards": self.shards, "restarts": self.restarts,
                     "retune": self.retune, "accept_band": self.accept_band,
                     "retune_bounds": self.retune_bounds,
                     "backend": self.backend, "workers": self.workers,
-                    "vmap_counts": self.vmap_counts,
                     "max_swaps": self.max_swaps})
         return cfg
 
@@ -448,22 +298,6 @@ class ShardedPortfolioRefiner:
         if self.shards > 1 and self.k * problem_size >= _MP_AUTO_MIN_ELEMS:
             return "mp"
         return "serial"
-
-    def _use_vmap_counts(self) -> bool:
-        """Whether the coordinator should precompute block counts with the
-        jax kernel.  Precomputing only to fall back to the numpy loop would
-        *duplicate* the exact work ``PortfolioCost.__init__`` does anyway,
-        so this is True only when the jax path will really run:
-        :func:`_resolve_counts_backend` must select jax (``"auto"`` =
-        jax importable — an environment property, never import order) and
-        the import must actually succeed."""
-        if not _resolve_counts_backend(self.vmap_counts):
-            return False
-        try:
-            import jax  # noqa: F401
-            return True
-        except ImportError:
-            return False
 
     # -- driver -------------------------------------------------------------
     def refine(self, grid: CartGrid, stencil: Stencil,
@@ -584,7 +418,7 @@ class ShardedPortfolioRefiner:
         weights = stencil.weight_array() if weighted else np.ones(stencil.k)
         t_scale = float(np.mean(weights))
         backend = self._resolve_backend(grid.size)
-        vmap_counts = backend == "serial" and self._use_vmap_counts()
+        precount = backend == "serial"
 
         # per-ladder start bookkeeping, identical floats to the
         # single-process engine (same integer counts, same ascending-offset
@@ -699,10 +533,9 @@ class ShardedPortfolioRefiner:
                                "done": blk["done"],
                                "temps": np.full(b.size, T),
                                "eps": np.full(b.size, eps0)}
-                    if vmap_counts:
+                    if precount:
                         payload["counts"] = stacked_crossing_counts(
-                            grid, stencil, blk["node"], n_nodes,
-                            use_jax=self.vmap_counts)
+                            grid, stencil, blk["node"], n_nodes)
                     payloads.append(payload)
                     specs.append(("orig", bi, b))
                 active = [r for r in restarts if not r["done"]]

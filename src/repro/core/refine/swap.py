@@ -8,26 +8,25 @@ with a crossing incident edge can gain from a swap with one of their
 stencil neighbours on a different node, which keeps a pass at
 O(|boundary| * k^2) delta evaluations instead of O(p^2).
 
-Two engines implement the same search:
+Each pass builds the whole candidate frontier as ``(P, Q)`` index arrays
+and scores every pair in one
+:meth:`~repro.core.cost_delta.IncrementalCost.batch_swap_deltas` call
+(whose rows equal the scalar
+:meth:`~repro.core.cost_delta.IncrementalCost.delta_swap` bit for bit).
+A steepest pass is then a single ``argmax`` over the gain array; a
+first-improvement pass applies a maximal set of spatially-disjoint
+improving swaps per batch (positions whose neighbourhood an accepted swap
+touched are masked out, so every applied delta is still exact).
 
-* ``engine="batch"`` (default) — builds the whole candidate frontier as
-  ``(P, Q)`` index arrays and scores every pair in one
-  :meth:`~repro.core.cost_delta.IncrementalCost.batch_swap_deltas` call.
-  A steepest pass is then a single ``argmax`` over the gain array; a
-  first-improvement pass applies a maximal set of spatially-disjoint
-  improving swaps per batch (positions whose neighbourhood an accepted
-  swap touched are masked out, so every applied delta is still exact).
-  Each pass records the spans ``swap.score`` (frontier and gains), with
-  ``swap.frontier`` (building the frontier) inside it, and ``swap.apply``,
-  and the counters ``swap.passes``, ``swap.pairs`` (pairs scored) and
-  ``swap.applied`` (see :mod:`repro.obs`).  Given a device scorer
-  (:mod:`repro.core.refine.device_swap`, which only the device portfolio
-  passes), the pairs are scored on the accelerator instead, with the same
-  integer values, and each pass adds its pairs to ``swap.device_pairs``
-  and the pair slots it dispatched (whole chunks) to
-  ``swap.device_slots`` as well.
-* ``engine="scalar"`` — the PR-1 per-vertex Python loop, kept as the
-  bit-exact reference the batch engine is tested and benchmarked against.
+Each pass records the spans ``swap.score`` (frontier and gains), with
+``swap.frontier`` (building the frontier) inside it, and ``swap.apply``,
+and the counters ``swap.passes``, ``swap.pairs`` (pairs scored) and
+``swap.applied`` (see :mod:`repro.obs`).  Given a device scorer
+(:mod:`repro.core.refine.device_swap`, which only the device portfolio
+passes), the pairs are scored on the accelerator instead, with the same
+integer values, and each pass adds its pairs to ``swap.device_pairs`` and
+the pair slots it dispatched (whole chunks) to ``swap.device_slots`` as
+well.
 
 Usage::
 
@@ -38,8 +37,8 @@ Usage::
 from __future__ import annotations
 
 import time
-from dataclasses import dataclass, field
-from typing import Optional, Sequence, Tuple
+from dataclasses import dataclass
+from typing import Optional, Tuple
 
 import numpy as np
 
@@ -53,7 +52,6 @@ __all__ = ["SwapRefiner", "RefineResult", "refine_assignment"]
 
 _OBJECTIVES = ("j_sum", "j_max")
 _POLICIES = ("first", "steepest")
-_ENGINES = ("batch", "scalar")
 
 #: j_max batch scoring materializes (chunk, N) load matrices; this bounds
 #: chunk * N so peak extra memory stays ~tens of MB regardless of frontier.
@@ -89,10 +87,9 @@ class SwapRefiner:
     Args:
       objective: "j_sum" (total inter-node edges) or "j_max" (bottleneck
         node's outgoing edges, J_sum as tie-break).
-      policy: "first" accepts improving swaps while scanning the boundary
-        (the batch engine applies a maximal spatially-disjoint set per
-        sweep); "steepest" scores the whole frontier each round and applies
-        the single best swap.
+      policy: "first" applies a maximal spatially-disjoint set of improving
+        swaps per sweep; "steepest" scores the whole frontier each round
+        and applies the single best swap.
       max_passes: full boundary sweeps before giving up.
       max_swaps: hard cap on accepted swaps (None = unlimited).
       weighted: score with the stencil's per-offset byte weights; the
@@ -108,24 +105,19 @@ class SwapRefiner:
         deterministic).  Partners are boundary vertices of the nodes p
         communicates with (KL/FM-style), which catches improving exchanges
         between cells that are not stencil neighbours of each other.
-      engine: "batch" (vectorized frontier scoring) or "scalar" (PR-1
-        reference loop).
       scorer: internal; a :class:`~repro.core.refine.device_swap.DeviceSwapScorer`
-        that scores the batch engine's pairs on the accelerator (None:
-        numpy).  It changes no result, so it is not part of ``config()``.
+        that scores the pairs on the accelerator (None: numpy).  It
+        changes no result, so it is not part of ``config()``.
     """
 
     def __init__(self, objective: str = "j_sum", policy: str = "first",
                  max_passes: int = 8, max_swaps: Optional[int] = None,
                  weighted="auto", tol: float = 1e-12,
-                 max_partners: int = 32, engine: str = "batch",
-                 scorer=None):
+                 max_partners: int = 32, scorer=None):
         if objective not in _OBJECTIVES:
             raise ValueError(f"objective must be one of {_OBJECTIVES}")
         if policy not in _POLICIES:
             raise ValueError(f"policy must be one of {_POLICIES}")
-        if engine not in _ENGINES:
-            raise ValueError(f"engine must be one of {_ENGINES}")
         if max_passes <= 0:
             raise ValueError("max_passes must be positive")
         self.objective = objective
@@ -135,7 +127,6 @@ class SwapRefiner:
         self.weighted = weighted
         self.tol = float(tol)
         self.max_partners = int(max_partners)
-        self.engine = engine
         self.scorer = scorer
 
     def as_stage(self, budget: Optional[int] = None):
@@ -150,7 +141,7 @@ class SwapRefiner:
         return {"objective": self.objective, "policy": self.policy,
                 "max_passes": self.max_passes, "max_swaps": self.max_swaps,
                 "weighted": self.weighted, "tol": self.tol,
-                "max_partners": self.max_partners, "engine": self.engine}
+                "max_partners": self.max_partners}
 
     def _tol(self, ic: IncrementalCost) -> float:
         """Acceptance threshold in the objective's own units: byte-weighted
@@ -170,14 +161,7 @@ class SwapRefiner:
         budget = self.max_swaps if self.max_swaps is not None else np.inf
         while passes < self.max_passes and swaps < budget:
             passes += 1
-            if self.engine == "scalar":
-                if self.policy == "steepest":
-                    improved, swaps = self._steepest_pass_scalar(ic, swaps,
-                                                                 budget)
-                else:
-                    improved, swaps = self._first_pass_scalar(ic, swaps,
-                                                              budget)
-            elif self.policy == "steepest":
+            if self.policy == "steepest":
                 improved, swaps = self._steepest_pass(ic, swaps, budget)
             else:
                 improved, swaps = self._first_pass(ic, swaps, budget)
@@ -187,7 +171,7 @@ class SwapRefiner:
                             final=ic.cost(), swaps=swaps, passes=passes,
                             wall_time_s=time.perf_counter() - t0)
 
-    # -- batch engine -------------------------------------------------------
+    # -- one pass -----------------------------------------------------------
     def _frontier_pairs(self, ic: IncrementalCost) \
             -> Tuple[np.ndarray, np.ndarray]:
         """All candidate swap pairs as (P, Q) arrays, deduplicated with
@@ -358,73 +342,6 @@ class SwapRefiner:
                     dirty_nodes |= affected[r]
         obs.count("swap.applied", swaps - start)
         return swaps > start, swaps
-
-    # -- scalar reference engine (PR-1 loop) --------------------------------
-    def _gain(self, ic: IncrementalCost, p: int, q: int) -> float:
-        """Positive improvement of the configured objective for swap (p, q)."""
-        delta = ic.delta_swap(p, q)
-        if self.objective == "j_sum":
-            return -delta.d_j_sum
-        # j_max: lexicographic (j_max, j_sum); fold the tie-break in with a
-        # weight small enough not to override a strict j_max improvement.
-        if not delta.d_count_node and delta.d_j_sum == 0.0:
-            return 0.0
-        d_max = ic.j_max - ic.peek_j_max(delta)  # both O(N) via cache
-        if d_max != 0.0:
-            return d_max
-        return -delta.d_j_sum * 1e-9 if delta.d_j_sum < 0 else 0.0
-
-    def _candidates(self, ic: IncrementalCost, p: int,
-                    boundary: np.ndarray) -> np.ndarray:
-        """Stencil-adjacent partners first (cheap locality), then boundary
-        vertices of the nodes p's crossing edges touch."""
-        node = ic.node_of_pos
-        nbrs = ic.neighbors_of(p)
-        adj = nbrs[node[nbrs] != node[p]]
-        touched = np.unique(node[adj])
-        if touched.size == 0:
-            return adj
-        far = boundary[np.isin(node[boundary], touched)]
-        far = far[~np.isin(far, adj)]
-        if far.size > self.max_partners:
-            idx = (np.arange(self.max_partners)
-                   * (far.size / self.max_partners)).astype(np.int64)
-            far = far[idx]
-        return np.concatenate([adj, far])
-
-    def _first_pass_scalar(self, ic: IncrementalCost, swaps: int,
-                           budget: float) -> Tuple[bool, int]:
-        improved = False
-        boundary = ic.boundary_positions()
-        tol = self._tol(ic)
-        for p in boundary:
-            if swaps >= budget:
-                break
-            for q in self._candidates(ic, p, boundary):
-                if self._gain(ic, p, int(q)) > tol:
-                    ic.apply_swap(p, int(q))
-                    swaps += 1
-                    improved = True
-                    break   # p's neighbourhood changed; move on
-        return improved, swaps
-
-    def _steepest_pass_scalar(self, ic: IncrementalCost, swaps: int,
-                              budget: float) -> Tuple[bool, int]:
-        """One full boundary sweep, then apply the single best swap — so a
-        steepest pass is one sweep and max_passes bounds total work."""
-        if swaps >= budget:
-            return False, swaps
-        best_gain, best = self._tol(ic), None
-        boundary = ic.boundary_positions()
-        for p in boundary:
-            for q in self._candidates(ic, p, boundary):
-                g = self._gain(ic, p, int(q))
-                if g > best_gain:
-                    best_gain, best = g, (int(p), int(q))
-        if best is None:
-            return False, swaps
-        ic.apply_swap(*best)
-        return True, swaps + 1
 
 
 def refine_assignment(grid: CartGrid, stencil: Stencil,

@@ -56,11 +56,11 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
-from ..core.cost_delta import IncrementalCost, PortfolioCost
+from ..core.cost_delta import IncrementalCost, PortfolioCost, neighbor_table
 from ..core.grid import CartGrid
 from ..core.refine.engine import BoundaryController, RestartSeeder
 from ..core.refine.sharded import (ShardedPortfolioRefiner, _block_step,
-                                   _memo_table, worker_context)
+                                   worker_context)
 from ..core.refine.portfolio import run_temperature
 from ..core.refine.swap import RefineResult
 from ..core.stencil import Stencil, resolve_weighted
@@ -98,7 +98,7 @@ class _WorkerBlock:
                                 np.asarray(payload["node"], dtype=np.int64),
                                 num_nodes=payload["num_nodes"],
                                 weighted=payload["weighted"],
-                                table=_memo_table(grid, stencil))
+                                table=neighbor_table(grid, stencil))
         self.rngs = [np.random.default_rng(s) for s in payload["seeds"]]
         self.done = np.zeros(len(self.rngs), dtype=bool)
         self.sa_moves = int(payload["sa_moves"])

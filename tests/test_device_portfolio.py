@@ -23,9 +23,10 @@ contract instead:
 * **K-scaling** — at equal total proposal budget, K=256 stacked ladders
   run under 4x the wall-time of K=8 (the bench pins the same claim at
   K=1024 in ``results/BENCH_7.json``);
-* **delegation** — ``max_swaps``/``pinned`` runs and jax-less
-  environments fall back to the single-process host portfolio, so every
-  ``device[...]:`` spelling works everywhere.
+* **delegation** — ``max_swaps``/``pinned`` runs fall back to the
+  single-process host portfolio;
+* **layering** — the device path takes the count state from the cost
+  core, never from the ``multiprocessing`` engine.
 """
 import copy
 import time
@@ -39,11 +40,10 @@ from hypothesis import given, settings, strategies as st
 
 from repro.core import (CartGrid, DevicePortfolioRefiner, PlanCache,
                         PortfolioRefiner, Stencil, available_mappers,
-                        evaluate, get_mapper, parse_plan,
-                        stacked_crossing_counts)
+                        evaluate, get_mapper, parse_plan)
+from repro.core.cost_delta import neighbor_table, stacked_count_arrays
 from repro.core.plan import MappingProblem
-from repro.core.refine.device import (DeviceLadderEngine, _threefry_keys,
-                                     jax_ready)
+from repro.core.refine.device import DeviceLadderEngine, _threefry_keys
 
 # the refine_suite --tiny instances (same rows as benchmarks.refine_suite)
 TINY = [
@@ -68,8 +68,8 @@ def _instance(seed, dims=(6, 7), n_nodes=5):
 
 def _exact_keys(grid, stencil, nodes, n_nodes):
     """Reference (J_max, J_sum) per row from a numpy recount."""
-    co, cn = stacked_crossing_counts(grid, stencil, nodes, n_nodes,
-                                     use_jax="numpy")
+    co, cn = stacked_count_arrays(neighbor_table(grid, stencil), nodes,
+                                  n_nodes)
     w = stencil.weight_array()
     per = (cn.astype(np.float64) * w[None, None, :]).sum(axis=2)
     return per.max(axis=1), (co.astype(np.float64) * w[None, :]).sum(axis=1)
@@ -93,8 +93,8 @@ def test_count_state_integer_exact_after_every_boundary():
         rep = eng.run_temperature(np.full(rows, T), 30, alive,
                                   np.full(rows, 1e-2))
         snap = eng.snapshot()
-        co, cn = stacked_crossing_counts(grid, W_STENCIL, snap["nodes"], 5,
-                                         use_jax="numpy")
+        co, cn = stacked_count_arrays(neighbor_table(grid, W_STENCIL),
+                                      snap["nodes"], 5)
         np.testing.assert_array_equal(cn, eng.counts())
         jm, js = _exact_keys(grid, W_STENCIL, snap["nodes"], 5)
         np.testing.assert_array_equal(rep.j_max, jm)
@@ -231,8 +231,7 @@ def test_engine_setup_makes_no_per_row_key_call(monkeypatch):
     eng = DeviceLadderEngine(grid, st_, start, seeds=tuple(range(64)),
                              num_nodes=5, restart_slots=64)
     assert calls == []
-    _, cn0 = stacked_crossing_counts(grid, st_, start[None], 5,
-                                     use_jax="numpy")
+    _, cn0 = stacked_count_arrays(neighbor_table(grid, st_), start[None], 5)
     for arr, row in ((eng._node, start), (eng._best_node, start),
                      (eng._cn, cn0[0])):
         arr = np.asarray(arr)
@@ -354,9 +353,25 @@ def test_budgeted_and_pinned_runs_delegate_to_host():
     np.testing.assert_array_equal(res.assignment[pinned], start[pinned])
 
 
-def test_jax_ready_probe_is_cached_and_true_here():
-    assert jax_ready() is True      # the test image bakes jax in
-    assert jax_ready() is True      # second call hits the cache
+@pytest.mark.parametrize("module", ["device", "device_swap"])
+def test_device_path_imports_nothing_from_sharded(module):
+    """The device refiner and its swap scorer take the count state and the
+    table memo from the cost core, not from the ``multiprocessing``
+    engine."""
+    import ast
+    import pathlib
+
+    import repro.core.refine as refine
+    path = pathlib.Path(refine.__file__).with_name(module + ".py")
+    for node in ast.walk(ast.parse(path.read_text())):
+        if isinstance(node, ast.ImportFrom):
+            names = [node.module or ""] + [a.name for a in node.names]
+        elif isinstance(node, ast.Import):
+            names = [a.name for a in node.names]
+        else:
+            continue
+        assert not any(n == "sharded" or n.endswith(".sharded")
+                       for n in names), ast.dump(node)
 
 
 def test_device_restarts_spawn_from_pool():

@@ -229,7 +229,7 @@ def test_spans_nest_in_time_on_the_host_plane(tmp_path, device_stage):
 def test_jitted_programs_keep_stable_names():
     import jax.numpy as jnp
     from repro.core.refine.device import _temperature_kernel
-    from repro.core.refine.sharded import _jit_stacked_counts
+    from repro.core.cost_delta import _jit_stacked_counts
     assert _temperature_kernel(5).__name__ == "ladder_temperature_scan"
     text = _jit_stacked_counts(3).lower(
         jnp.zeros((2, 6), jnp.int32), jnp.zeros((4, 6), bool),

@@ -2,7 +2,8 @@
 K-state deltas with K scalar IncrementalCost tracks, the portfolio-vs-
 annealed dominance guarantee (ladder 0 reproduces the scalar annealed
 trajectory), early-kill behaviour, the `portfolio[k=8]:` option-parsing
-grammar, and weighted="auto" resolution through the refine stack.
+grammar, weighted="auto" resolution through the refine stack, and the jax
+kernel that builds the integer count state against the numpy loop.
 
 Parity assertions use == / array_equal, not isclose: the portfolio path
 keeps the same integer crossing counts and the same ascending-offset float
@@ -15,10 +16,13 @@ import pytest
 
 from hypothesis import given, settings, strategies as st
 
-from repro.core import (CartGrid, IncrementalCost, PortfolioCost,
-                        PortfolioRefiner, RefinedMapper, ScheduledRefiner,
-                        Stencil, SwapRefiner, available_mappers, evaluate,
-                        get_mapper, parse_mapper_options, split_mapper_name)
+from repro.core import (CartGrid, CommGraph, IncrementalCost, MaskedGrid,
+                        NeighborTable, PortfolioCost, PortfolioRefiner,
+                        RefinedMapper, ScheduledRefiner, Stencil, SwapRefiner,
+                        available_mappers, evaluate, get_mapper,
+                        parse_mapper_options, split_mapper_name,
+                        stacked_crossing_counts)
+from repro.core.cost_delta import neighbor_table, stacked_count_arrays
 
 STENCILS = {
     "nn": Stencil.nearest_neighbor,
@@ -109,6 +113,83 @@ def test_portfolio_cost_validates_input():
                            with_counts=True)
     assert empty.size == 0
     pc.commit(empty)                                 # no-op commit is fine
+
+
+# ---------------------------------------------------------------------------
+# the integer count state: the jax kernel against the numpy loop
+
+
+def _perms(rng, K, sizes):
+    """K random layouts holding ``sizes[n]`` positions on node n."""
+    base = np.repeat(np.arange(len(sizes)), sizes)
+    return np.stack([rng.permutation(base) for _ in range(K)])
+
+
+def _graph_case(rng):
+    src = rng.integers(0, 24, size=60)
+    dst = rng.integers(0, 24, size=60)
+    g = CommGraph.from_edges(24, src, dst, weights=rng.integers(1, 4, 60))
+    return g.grid(), g.slot_stencil(), rng.integers(0, 3, size=(3, 24)), 3
+
+
+#: (grid, stencil, (K, p) assignments, num_nodes) per case
+COUNT_CASES = {
+    "mixed-periodic-hops": lambda rng: (
+        CartGrid((5, 6), periodic=(True, False)), Stencil.nn_with_hops(2),
+        rng.integers(0, 4, size=(3, 30)), 4),
+    # the paper's largest Fig. 8 instances: 992 processes on 31 nodes
+    "fig8-2d": lambda rng: (CartGrid((32, 31)), Stencil.nearest_neighbor(2),
+                            _perms(rng, 4, [32] * 31), 31),
+    "fig8-3d": lambda rng: (CartGrid((31, 8, 4)), Stencil.nearest_neighbor(3),
+                            _perms(rng, 4, [32] * 31), 31),
+    "component": lambda rng: (CartGrid((6, 7)), Stencil.component(2),
+                              rng.integers(0, 5, size=(3, 42)), 5),
+    "masked": lambda rng: (
+        MaskedGrid(CartGrid((6, 6), periodic=(True, True)),
+                   np.arange(36) % 3 != 0),
+        Stencil.nearest_neighbor(2), rng.integers(0, 4, size=(3, 36)), 4),
+    "comm-graph": _graph_case,
+    # one row: the device engine's start and a restart's spawn
+    "one-row": lambda rng: (CartGrid((8, 8)), Stencil.nearest_neighbor(2),
+                            _perms(rng, 1, [16] * 4), 4),
+    # nodes that hold no position
+    "spare-nodes": lambda rng: (CartGrid((6, 5)), Stencil.nearest_neighbor(2),
+                                rng.integers(0, 3, size=(3, 30)), 7),
+}
+
+
+@pytest.mark.parametrize("case", sorted(COUNT_CASES))
+def test_stacked_crossing_counts_matches_portfolio_cost(case):
+    """The jax kernel's integer counts equal the numpy loop's and
+    PortfolioCost's own init, bit for bit, and feeding them back as
+    ``counts=`` reproduces the full state.  The table memo serves a
+    masked or graph-backed grid its own table, never the one of a plain
+    grid with the same dims."""
+    rng = np.random.default_rng(11)
+    grid, stencil, A, n_nodes = COUNT_CASES[case](rng)
+    neighbor_table(CartGrid(tuple(grid.dims), periodic=tuple(grid.periodic)),
+                   stencil)
+    ref = NeighborTable.build(grid, stencil)
+    table = neighbor_table(grid, stencil)
+    for name in ("out_valid", "out_tgt", "in_valid", "in_src"):
+        np.testing.assert_array_equal(getattr(table, name),
+                                      getattr(ref, name))
+    co, cn = stacked_crossing_counts(grid, stencil, A, n_nodes)
+    assert co.dtype == cn.dtype == np.int64
+    assert cn.shape == (A.shape[0], n_nodes, stencil.k)
+    co_np, cn_np = stacked_count_arrays(ref, A, n_nodes)
+    np.testing.assert_array_equal(co, co_np)
+    np.testing.assert_array_equal(cn, cn_np)
+    pc = PortfolioCost(grid, stencil, A, num_nodes=n_nodes)
+    np.testing.assert_array_equal(co, pc._count_off)
+    np.testing.assert_array_equal(cn, pc._count_node)
+    pre = PortfolioCost(grid, stencil, A, num_nodes=n_nodes, counts=(co, cn))
+    np.testing.assert_array_equal(pre.per_node(), pc.per_node())
+    assert pre.j_sum().tolist() == pc.j_sum().tolist()
+    assert pre.j_max().tolist() == pc.j_max().tolist()
+    with pytest.raises(ValueError, match="wrong shapes"):
+        PortfolioCost(grid, stencil, A, num_nodes=n_nodes,
+                      counts=(co, cn[:, :-1]))
 
 
 # ---------------------------------------------------------------------------

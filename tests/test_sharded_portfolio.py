@@ -1,8 +1,8 @@
 """Sharded adaptive portfolio engine: shard-count invariance (bit-identical
 to the single-process portfolio for any shard count), multiprocessing-
 backend parity, restart-from-leader dominance, accept-rate retune bounds,
-the killed-budget pool accounting, the `sharded[...]:` grammar/plan/cache
-wiring, and the jax.vmap stacked-counts path.
+the killed-budget pool accounting, and the `sharded[...]:`
+grammar/plan/cache wiring.
 
 Invariance assertions use array_equal / ==, not isclose: the sharded
 coordinator replays the single-process engine's floats exactly (same
@@ -13,11 +13,10 @@ import pytest
 
 from hypothesis import given, settings, strategies as st
 
-from repro.core import (CartGrid, PlanCache, PortfolioCost, PortfolioRefiner,
-                        RefinedMapper, ShardedPortfolioRefiner, Stencil,
-                        available_mappers, device_layout, ensure_refined,
-                        evaluate, get_mapper, parse_plan,
-                        stacked_crossing_counts)
+from repro.core import (CartGrid, PlanCache, PortfolioRefiner, RefinedMapper,
+                        ShardedPortfolioRefiner, Stencil, available_mappers,
+                        device_layout, ensure_refined, evaluate, get_mapper,
+                        parse_plan)
 
 #: a schedule small enough for tests but long enough that kills, restarts,
 #: and several retune boundaries actually happen.
@@ -285,115 +284,6 @@ def test_budgeted_sharded_delegates_to_single_process():
         np.testing.assert_array_equal(sh.assignment, pf.assignment)
         assert sh.stats["swaps"] <= budget
         assert sh.result.stats["backend"] == "single-process"
-
-
-# ---------------------------------------------------------------------------
-# the jax.vmap stacked-counts path
-
-
-def test_stacked_crossing_counts_matches_portfolio_cost():
-    """The counts kernel (numpy path, and the jax.vmap path when jax is
-    importable) is bit-equal to PortfolioCost's own init loop, and feeding
-    the counts back in reproduces the full state."""
-    rng = np.random.default_rng(11)
-    grid = CartGrid((5, 6), periodic=(True, False))
-    stencil = Stencil.nn_with_hops(2)
-    A = rng.integers(0, 4, size=(3, grid.size))
-    pc = PortfolioCost(grid, stencil, A, num_nodes=4)
-    co, cn = stacked_crossing_counts(grid, stencil, A, 4, use_jax=False)
-    np.testing.assert_array_equal(co, pc._count_off)
-    np.testing.assert_array_equal(cn, pc._count_node)
-    try:
-        import jax  # noqa: F401
-        co_j, cn_j = stacked_crossing_counts(grid, stencil, A, 4,
-                                             use_jax=True)
-        np.testing.assert_array_equal(co_j, co)
-        np.testing.assert_array_equal(cn_j, cn)
-    except ImportError:
-        pass
-    pre = PortfolioCost(grid, stencil, A, num_nodes=4, counts=(co, cn))
-    np.testing.assert_array_equal(pre.per_node(), pc.per_node())
-    assert pre.j_sum().tolist() == pc.j_sum().tolist()
-    with pytest.raises(ValueError, match="wrong shapes"):
-        PortfolioCost(grid, stencil, A, num_nodes=4, counts=(co, cn[:2]))
-
-
-def test_vmap_counts_refine_is_bit_identical():
-    """vmap_counts only changes who computes the integer counts — the
-    refinement result must not move."""
-    grid, stencil, a = _kill_instance(3)
-    kw = dict(shards=2, k=4, seed=3, backend="serial",
-              rounds=1, max_passes=2, sa_moves=40)
-    off = ShardedPortfolioRefiner(vmap_counts=False, **kw).refine(
-        grid, stencil, a, num_nodes=len(KILL_SIZES))
-    on = ShardedPortfolioRefiner(vmap_counts=True, **kw).refine(
-        grid, stencil, a, num_nodes=len(KILL_SIZES))
-    np.testing.assert_array_equal(off.assignment, on.assignment)
-    assert off.stats["ladder_keys"] == on.stats["ladder_keys"]
-
-
-# ---------------------------------------------------------------------------
-# counts backend selection: explicit option, import-order independence
-
-
-def test_counts_backend_explicit_values_bit_equal():
-    """"numpy" and "jax" are explicit backend spellings; both produce the
-    same integer counts, and bogus values are rejected at construction."""
-    rng = np.random.default_rng(13)
-    grid = CartGrid((6, 5))
-    stencil = Stencil.nn_with_hops(2)
-    A = rng.integers(0, 4, size=(3, grid.size))
-    co_n, cn_n = stacked_crossing_counts(grid, stencil, A, 4,
-                                         use_jax="numpy")
-    co_j, cn_j = stacked_crossing_counts(grid, stencil, A, 4, use_jax="jax")
-    np.testing.assert_array_equal(co_n, co_j)
-    np.testing.assert_array_equal(cn_n, cn_j)
-    with pytest.raises(ValueError, match="vmap_counts"):
-        ShardedPortfolioRefiner(vmap_counts="cuda")
-    # the option is part of config(), so it is cache-identity material
-    assert ShardedPortfolioRefiner(
-        vmap_counts="numpy").config()["vmap_counts"] == "numpy"
-
-
-def test_counts_backend_auto_is_importability_not_import_order():
-    """Regression (satellite): "auto" used to consult sys.modules, so the
-    first call's backend depended on whether anything had imported jax
-    yet.  It must key on *importability* (find_spec) — stable for the
-    process regardless of import order."""
-    import importlib.util
-    import sys
-
-    from repro.core.refine import sharded as sh
-
-    assert "jax" in sys.modules        # the suite has long since imported it
-    spec_backup = sh._JAX_SPEC
-    real_find_spec = importlib.util.find_spec
-    try:
-        # simulate a jax-less environment; with jax still in sys.modules,
-        # the old sys.modules probe would (wrongly) say "jax"
-        sh._JAX_SPEC = None
-        importlib.util.find_spec = lambda name, *a: (
-            None if name == "jax" else real_find_spec(name, *a))
-        assert sh._jax_importable() is False
-        assert sh._resolve_counts_backend("auto") is False
-        # and the cached verdict is sticky: restoring find_spec without
-        # resetting the cache does not flip it mid-process
-        importlib.util.find_spec = real_find_spec
-        assert sh._resolve_counts_backend("auto") is False
-    finally:
-        importlib.util.find_spec = real_find_spec
-        sh._JAX_SPEC = spec_backup
-    # back in the real environment: importable, so "auto" means jax
-    sh._JAX_SPEC = None
-    try:
-        assert sh._resolve_counts_backend("auto") is True
-    finally:
-        sh._JAX_SPEC = spec_backup
-    # explicit spellings resolve independently of the probe
-    assert sh._resolve_counts_backend("numpy") is False
-    assert sh._resolve_counts_backend("jax") is True
-    assert sh._resolve_counts_backend(True) is True
-    assert sh._resolve_counts_backend(False) is False
 
 
 # ---------------------------------------------------------------------------

@@ -19,10 +19,10 @@ from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
                           SingleDeviceSharding)
 
 from repro.core import CartGrid, Stencil, cart_create
+from repro.core.cost_delta import _jit_stacked_counts, neighbor_table
 from repro.core.remap import apply_layout
 from repro.core.refine.device import _temperature_kernel
 from repro.core.refine.device_swap import CHUNK, _scores_kernel
-from repro.core.refine.sharded import _jit_stacked_counts, _memo_table
 from repro.kernels.stencil.ops import stencil_apply
 from repro.kernels.stencil.jacobi import jacobi_sweeps, jacobi_taps
 from repro.kernels.stencil.stencil import stencil3d_pallas
@@ -115,7 +115,7 @@ def test_temperature_kernel_compiles_for_v5e(one_chip):
 def test_stacked_counts_compile_for_v5e(one_chip):
     """The count-state seeding / rekeying kernel over 2K candidates."""
     grid = CartGrid(LADDER_DIMS)
-    table = _memo_table(grid, Stencil.nearest_neighbor(2))
+    table = neighbor_table(grid, Stencil.nearest_neighbor(2))
     k, p = table.out_valid.shape
     compiled = _jit_stacked_counts(LADDER_PODS).lower(
         _shape((LADDER_ROWS, p), jnp.int32, one_chip),
