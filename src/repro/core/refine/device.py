@@ -86,6 +86,88 @@ from .swap import RefineResult
 __all__ = ["DeviceLadderEngine", "DevicePortfolioRefiner"]
 
 
+def _row_lookup(values, idx):
+    """``values[r, idx[r, ...]]`` for ``(R, p)`` values and ``(R, ...)``
+    positions, as a select-and-sum over the p positions: one fused,
+    lane-parallel reduction (no ``(R, ..., p)`` intermediate is stored).
+    A position outside ``[0, p)`` reads 0."""
+    import jax
+    import jax.numpy as jnp
+    R, p = values.shape
+    x = jax.lax.broadcasted_iota(jnp.int32, idx.shape + (p,), idx.ndim)
+    v = values.reshape((R,) + (1,) * (idx.ndim - 1) + (p,))
+    return jnp.where(x == idx[..., None], v, 0).sum(axis=-1)
+
+
+def _swap_delta(node, pi, qi, a, b, n_nodes, out_valid, out_tgt, in_valid,
+                in_src):
+    """The exact integer ``count_node`` delta of swapping positions
+    ``pi``/``qi`` (whose nodes are ``a``/``b``) in every row of ``node``,
+    read with no indexed gather: ``(nbr, d_cn)``.
+
+    Only edges with an endpoint in {p, q} change crossing status: the four
+    directed edge groups out of p, out of q, into p and into q, each over
+    the k offsets, in-edges from p or q deduped against the out groups.
+    ``nbr`` (R, 4, k) is the current node of each group's other endpoint
+    (``out_tgt[:, p]``, ``out_tgt[:, q]``, ``in_src[:, p]``,
+    ``in_src[:, q]``; 0 where the edge is invalid).  ``d_cn`` (R, N, k)
+    is dense over (N, k): group g's edge at offset j moves a crossing of
+    offset j off its source's old node ``s_old`` and onto its new one
+    ``s_new``, so ``d_cn[r, n, j] = sum_g new_c[r, g, j] [s_new == n] -
+    old_c[r, g, j] [s_old == n]``.
+    """
+    import jax.numpy as jnp
+    R, p = node.shape
+    k = out_tgt.shape[0]
+    # the tables' columns at p and q in one reduction; -1 marks no edge
+    tables = jnp.concatenate([jnp.where(out_valid, out_tgt, -1),
+                              jnp.where(in_valid, in_src, -1)])    # (2k, p)
+    at = jnp.arange(p, dtype=jnp.int32) == jnp.stack(
+        [pi, qi], axis=1)[:, :, None]                             # (R, 2, p)
+    cols = jnp.where(at[:, :, None, :], tables, 0).sum(axis=-1)  # (R, 2, 2k)
+    out_p, out_q = cols[:, 0, :k], cols[:, 1, :k]
+    in_p, in_q = cols[:, 0, k:], cols[:, 1, k:]
+    nbr = _row_lookup(node, jnp.stack([out_p, out_q, in_p, in_q], axis=1))
+    P = jnp.broadcast_to(pi[:, None], (R, k))
+    Q = jnp.broadcast_to(qi[:, None], (R, k))
+    A = jnp.broadcast_to(a[:, None], (R, k))
+    B = jnp.broadcast_to(b[:, None], (R, k))
+    src = jnp.stack([P, Q, in_p, in_q], axis=1)                 # (R, 4, k)
+    dst = jnp.stack([out_p, out_q, P, Q], axis=1)
+    s_old = jnp.stack([A, B, nbr[:, 2], nbr[:, 3]], axis=1)
+    d_old = jnp.stack([nbr[:, 0], nbr[:, 1], A, B], axis=1)
+
+    def remap(pos, cur):            # node at pos after the swap
+        return jnp.where(pos == pi[:, None, None], b[:, None, None],
+                         jnp.where(pos == qi[:, None, None],
+                                   a[:, None, None], cur))
+
+    s_new, d_new = remap(src, s_old), remap(dst, d_old)
+    valid = jnp.stack([out_p >= 0, out_q >= 0,
+                       (in_p >= 0) & (in_p != P) & (in_p != Q),
+                       (in_q >= 0) & (in_q != P) & (in_q != Q)], axis=1)
+    old_c = valid & (s_old != d_old)
+    new_c = valid & (s_new != d_new)
+    n = jnp.arange(n_nodes, dtype=node.dtype)[:, None]          # (N, 1)
+    inc = (new_c[:, :, None, :] & (s_new[:, :, None, :] == n)).sum(
+        axis=1, dtype=jnp.int32)
+    dec = (old_c[:, :, None, :] & (s_old[:, :, None, :] == n)).sum(
+        axis=1, dtype=jnp.int32)
+    return nbr, inc - dec
+
+
+def _swap_rows(node, pi, qi, a, b, accept):
+    """Every accepting row of ``node`` with positions ``pi``/``qi`` set to
+    ``b``/``a`` (q's write wins where they coincide, as with two indexed
+    sets): one masked select over (R, p), no scatter."""
+    import jax
+    import jax.numpy as jnp
+    x = jax.lax.broadcasted_iota(jnp.int32, node.shape, 1)
+    acc = accept[:, None]
+    return jnp.where(acc & (x == qi[:, None]), a[:, None],
+                     jnp.where(acc & (x == pi[:, None]), b[:, None], node))
+
+
 @functools.lru_cache(maxsize=16)
 def _temperature_kernel(sa_moves: int):
     """Build (and cache) the jitted one-temperature kernel: ``sa_moves``
@@ -101,6 +183,15 @@ def _temperature_kernel(sa_moves: int):
     report leaves.  The jitted function keeps the stable name
     ``ladder_temperature_scan`` (module ``jit_ladder_temperature_scan``),
     so a trace reduction can find it after a refactor.
+
+    The scan step holds no indexed gather from, or scatter into, the
+    per-row state: the nodes at p and q, the swap's neighbour lookups and
+    table columns (:func:`_swap_delta`), its count delta (dense over
+    (N, k)) and the row swap (:func:`_swap_rows`) are masked selects and
+    compare-and-sums over the positions.  On the TPU an indexed gather or
+    scatter is serialised index by index and cost ~30-120 µs a move each
+    at the benchmark's sizes, about 80% of the kernel; the dense forms
+    give the same integers, so the walks are the same bit for bit.
     """
     import jax
     import jax.numpy as jnp
@@ -108,9 +199,7 @@ def _temperature_kernel(sa_moves: int):
     def ladder_temperature_scan(node, cn, keys, best_node, best_jmax,
                                 best_jsum, done, live, temps, eps, weights,
                                 out_valid, out_tgt, in_valid, in_src):
-        R, p = node.shape
-        N = cn.shape[1]
-        k = cn.shape[2]
+        R, N = cn.shape[:2]
 
         def loads(c):                           # (R, N, k) int -> (R, N)
             return jnp.einsum("rnk,k->rn", c.astype(jnp.float32), weights)
@@ -129,43 +218,6 @@ def _temperature_kernel(sa_moves: int):
         active = live & ~done
         logit_p = jnp.where(bmask, 0.0, -jnp.inf)               # (R, p)
 
-        def ladder_delta(node_r, p_r, q_r, a_r, b_r):
-            """Exact integer count_node delta of swapping positions
-            ``p_r``/``q_r`` in one ladder: only edges with an endpoint in
-            {p, q} change crossing status — the four directed edge groups,
-            in-edges deduped against the out groups."""
-            src = jnp.concatenate([
-                jnp.full((k,), p_r, dtype=node_r.dtype),
-                jnp.full((k,), q_r, dtype=node_r.dtype),
-                in_src[:, p_r], in_src[:, q_r]])
-            dst = jnp.concatenate([
-                out_tgt[:, p_r], out_tgt[:, q_r],
-                jnp.full((k,), p_r, dtype=node_r.dtype),
-                jnp.full((k,), q_r, dtype=node_r.dtype)])
-            valid = jnp.concatenate([
-                out_valid[:, p_r], out_valid[:, q_r],
-                in_valid[:, p_r] & (in_src[:, p_r] != p_r)
-                & (in_src[:, p_r] != q_r),
-                in_valid[:, q_r] & (in_src[:, q_r] != p_r)
-                & (in_src[:, q_r] != q_r)])
-            off = jnp.tile(jnp.arange(k, dtype=jnp.int32), 4)
-
-            def remap(x):               # node of x after the swap
-                return jnp.where(x == p_r, b_r,
-                                 jnp.where(x == q_r, a_r, node_r[x]))
-
-            s_old, d_old = node_r[src], node_r[dst]
-            s_new, d_new = remap(src), remap(dst)
-            old_c = valid & (s_old != d_old)
-            new_c = valid & (s_new != d_new)
-            dec = jax.ops.segment_sum(old_c.astype(jnp.int32),
-                                      s_old * k + off, num_segments=N * k)
-            inc = jax.ops.segment_sum(new_c.astype(jnp.int32),
-                                      s_new * k + off, num_segments=N * k)
-            return (inc - dec).reshape(N, k)
-
-        rows = jnp.arange(R)
-
         def move(carry, _):
             node, cn, keys, bnode, bjmax, bjsum, acc = carry
             ks = jax.vmap(lambda kk: jax.random.split(kk, 4))(keys)
@@ -173,13 +225,14 @@ def _temperature_kernel(sa_moves: int):
             # position, then cross-node partner, both from the snapshot
             # (current node values, like the host kernel's partner check)
             pi = jax.vmap(jax.random.categorical)(kp, logit_p)      # (R,)
-            a = jnp.take_along_axis(node, pi[:, None], axis=1)[:, 0]
+            a = _row_lookup(node, pi[:, None])[:, 0]
             partner = bmask & (node != a[:, None])
             has_q = partner.any(axis=1)
             qi = jax.vmap(jax.random.categorical)(
                 kq, jnp.where(partner, 0.0, -jnp.inf))
-            b = jnp.take_along_axis(node, qi[:, None], axis=1)[:, 0]
-            d_cn = jax.vmap(ladder_delta)(node, pi, qi, a, b)
+            b = _row_lookup(node, qi[:, None])[:, 0]
+            _, d_cn = _swap_delta(node, pi, qi, a, b, N, out_valid, out_tgt,
+                                  in_valid, in_src)
             cn_new = cn + d_cn
             jmax_old = loads(cn).max(axis=1)
             jmax_new = loads(cn_new).max(axis=1)
@@ -189,8 +242,7 @@ def _temperature_kernel(sa_moves: int):
             u = jax.vmap(jax.random.uniform)(ku)
             accept = active & has_q & ((d_e <= 0.0)
                                        | (u < jnp.exp(-d_e / temps)))
-            node_sw = node.at[rows, pi].set(b).at[rows, qi].set(a)
-            node = jnp.where(accept[:, None], node_sw, node)
+            node = _swap_rows(node, pi, qi, a, b, accept)
             cn = jnp.where(accept[:, None, None], cn_new, cn)
             acc = acc + accept.astype(jnp.int32)
             # device-side best-seen: strict lexicographic improvement only,

@@ -11,6 +11,9 @@ The topology is described inside a module fixture (never at import), and
 the persistent compile cache is off around these compiles: an entry
 compiled for a described chip cannot be read back without one.
 """
+import math
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -34,6 +37,9 @@ SHARD = 2048
 LADDER_DIMS, LADDER_PODS, LADDER_ROWS, LADDER_MOVES = (64, 64), 256, 2048, 200
 #: v5e HBM per chip
 HBM_BYTES = 16 * 2**30
+#: the benchmark cells' device solve: 992 processes on 31 nodes, K=256
+#: ladders plus as many restart slots
+CELL_ROWS, CELL_P, CELL_NODES = 512, 992, 31
 
 
 @pytest.fixture(scope="module")
@@ -84,13 +90,9 @@ def test_stencil3d_kernel_compiles_for_v5e(one_chip):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def test_temperature_kernel_compiles_for_v5e(one_chip):
-    """The device refiner's one-temperature scan at fleet size fits HBM."""
-    p = int(np.prod(LADDER_DIMS))
-    k = Stencil.nearest_neighbor(2).k
-    R, N = LADDER_ROWS, LADDER_PODS
+def _temperature_args(one_chip, R, p, N, k):
     i32, f32 = jnp.int32, jnp.float32
-    args = [_shape((R, p), i32, one_chip),          # node
+    return [_shape((R, p), i32, one_chip),          # node
             _shape((R, N, k), i32, one_chip),       # count state
             _shape((R, 2), jnp.uint32, one_chip),   # rng keys
             _shape((R, p), i32, one_chip),          # best node
@@ -105,11 +107,67 @@ def test_temperature_kernel_compiles_for_v5e(one_chip):
             _shape((k, p), i32, one_chip),          # out_tgt
             _shape((k, p), jnp.bool_, one_chip),    # in_valid
             _shape((k, p), i32, one_chip)]          # in_src
+
+
+def test_temperature_kernel_compiles_for_v5e(one_chip):
+    """The device refiner's one-temperature scan at fleet size fits HBM."""
+    args = _temperature_args(one_chip, LADDER_ROWS, int(np.prod(LADDER_DIMS)),
+                             LADDER_PODS, Stencil.nearest_neighbor(2).k)
     compiled = _temperature_kernel(LADDER_MOVES).lower(*args).compile()
     mem = compiled.memory_analysis()
     used = (mem.temp_size_in_bytes + mem.argument_size_in_bytes
             + mem.output_size_in_bytes)
     assert 0 < used < HBM_BYTES
+
+
+def _while_body_ops(hlo: str, ops=("gather", "scatter")):
+    """``(op, operand element count)`` of every ``ops`` instruction in the
+    computations a compiled module's ``while`` bodies reach."""
+    comps, name = {}, None
+    for line in hlo.splitlines():
+        head = re.match(r"(?:ENTRY )?%(\S+) .*\{$", line)
+        if head:
+            name = head.group(1)
+            comps[name] = [line]
+        elif name is not None:
+            comps[name].append(line)
+            if line == "}":
+                name = None
+    todo = re.findall(r"\bwhile\(.*?body=%([\w.\-]+)", hlo)
+    assert todo, "no while loop in the compiled module"
+    seen = set()
+    while todo:
+        c = todo.pop()
+        if c in seen:
+            continue
+        seen.add(c)
+        todo += [r for r in re.findall(r"%([\w.\-]+)", "\n".join(comps[c]))
+                 if r in comps]
+    found = []
+    for c in seen:
+        text = "\n".join(comps[c])
+        size = {n: math.prod(int(d) for d in dims.split(",") if d)
+                for n, dims in re.findall(
+                    r"%?([\w.\-]+):? (?:= )?\w+\[([\d,]*)\]", text)}
+        for op, operand in re.findall(r" (%s)\(%%([\w.\-]+)" % "|".join(ops),
+                                      text):
+            found.append((op, size.get(operand)))
+    return found
+
+
+@pytest.mark.parametrize("k", [4, 6], ids=["2d", "3d"])
+def test_temperature_scan_body_has_no_row_state_gather(one_chip, k):
+    """At the cells' sizes the scan step reads and writes the per-row
+    state by masked selects: no gather from, or scatter into, the (R, p)
+    assignment or the (R, N, k) count state (flattened or not) is left in
+    the loop body, and nothing of (R, 4k, p) is stored."""
+    R, p, N = CELL_ROWS, CELL_P, CELL_NODES
+    compiled = _temperature_kernel(200).lower(
+        *_temperature_args(one_chip, R, p, N, k)).compile()
+    row_state = [(op, n) for op, n in _while_body_ops(compiled.as_text())
+                 if n in (R * p, R * N * k)]
+    assert row_state == []
+    assert compiled.memory_analysis().temp_size_in_bytes < 16 * 2**20
 
 
 def test_stacked_counts_compile_for_v5e(one_chip):
